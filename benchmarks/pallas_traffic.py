@@ -61,7 +61,8 @@ def run() -> bool:
         ref = reference(prog, arrays, scalars)
         for mode in ("naive", "paper", "tile"):
             out, dt = timed(stencil_apply, prog, arrays, scalars, mode=mode,
-                            block={2: (8, 32), 3: (1, 8, 32)}[nd], repeat=1)
+                            block={2: (8, 32), 3: (1, 8, 32)}[nd],
+                            interpret=True, repeat=1)
             err = float(jnp.max(jnp.abs(out - ref)))
             ok &= err < 1e-3
             emit(f"pallas.{name}.{mode}.interpret_s", dt, "s",

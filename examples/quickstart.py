@@ -98,7 +98,8 @@ def main():
     scal = {"c0": .5, "c1": .25, "c2": .125}
     ref = reference(prog, arrays, scal)
     for mode in ("naive", "paper", "tile"):
-        out = stencil_apply(prog, arrays, scal, mode=mode, block=(8, 32))
+        out = stencil_apply(prog, arrays, scal, mode=mode, block=(8, 32),
+                            interpret=True)
         assert float(jnp.max(jnp.abs(out - ref))) < 1e-5
     t = traffic_report(prog, (32768, 32768))
     print("\n== TPU Pallas port (32768x32768) ==")

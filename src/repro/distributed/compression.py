@@ -21,9 +21,8 @@ from __future__ import annotations
 from typing import Any, Tuple
 
 import jax
-
-from repro.compat import shard_map
 import jax.numpy as jnp
+from jax import shard_map
 from jax.sharding import PartitionSpec as P
 
 

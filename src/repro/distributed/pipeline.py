@@ -13,9 +13,8 @@ from __future__ import annotations
 from typing import Any, Callable
 
 import jax
-
-from repro.compat import shard_map
 import jax.numpy as jnp
+from jax import shard_map
 from jax.sharding import PartitionSpec as P
 
 

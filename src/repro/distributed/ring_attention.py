@@ -23,9 +23,8 @@ import math
 from typing import Tuple
 
 import jax
-
-from repro.compat import shard_map
 import jax.numpy as jnp
+from jax import shard_map
 from jax.sharding import PartitionSpec as P
 
 _NEG_INF = -1e30

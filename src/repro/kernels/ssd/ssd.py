@@ -29,44 +29,50 @@ from jax.experimental.pallas import tpu as pltpu
 
 def _kernel(x_ref, dt_ref, a_ref, b_ref, c_ref, o_ref, state, *,
             Q: int, N: int, P: int):
+    h = pl.program_id(1)
     ci = pl.program_id(2)                     # chunk index (sequential)
 
     @pl.when(ci == 0)
     def _reset():
         state[...] = jnp.zeros((N, P), jnp.float32)
 
-    x = x_ref[0, 0, :, 0, :].astype(jnp.float32)        # (Q, P)
-    dt = dt_ref[0, 0, :, 0].astype(jnp.float32)         # (Q,)
-    A = a_ref[0].astype(jnp.float32)                    # scalar (negative)
+    x = x_ref[0, 0].astype(jnp.float32)                 # (Q, P)
+    dt_row = dt_ref[0, 0].astype(jnp.float32)           # (1, Q)
+    A = a_ref[h]                                        # scalar (negative)
     Bm = b_ref[0, 0].astype(jnp.float32)                # (Q, N)
     Cm = c_ref[0, 0].astype(jnp.float32)                # (Q, N)
 
-    dA = dt * A                                         # (Q,)
-    cum = jnp.cumsum(dA)
-    total = cum[-1]
+    # cumulative decay along the chunk, as a column and as a row, by
+    # masked reductions (no cumsum or transpose on the chip)
+    row = jax.lax.broadcasted_iota(jnp.int32, (Q, Q), 0)
+    col = jax.lax.broadcasted_iota(jnp.int32, (Q, Q), 1)
+    dt = jnp.sum(jnp.where(row == col, dt_row, 0.0), axis=1,
+                 keepdims=True)                         # (Q, 1)
+    dA_row = dt_row * A
+    cum = jnp.sum(jnp.where(col <= row, dA_row, 0.0), axis=1,
+                  keepdims=True)                        # (Q, 1)
+    cum_row = jnp.sum(jnp.where(row <= col, dt * A, 0.0), axis=0,
+                      keepdims=True)                    # (1, Q)
+    total = jnp.sum(dA_row, axis=1, keepdims=True)      # (1, 1)
     # intra-chunk decay matrix, causal-masked
-    diff = cum[:, None] - cum[None, :]
-    mask = jax.lax.broadcasted_iota(jnp.int32, (Q, Q), 0) >= \
-        jax.lax.broadcasted_iota(jnp.int32, (Q, Q), 1)
-    decay = jnp.where(mask, jnp.exp(diff), 0.0)
-    xdt = x * dt[:, None]                               # (Q, P)
+    decay = jnp.where(row >= col, jnp.exp(cum - cum_row), 0.0)
+    xdt = x * dt                                        # (Q, P)
     cb = jax.lax.dot_general(Cm, Bm, (((1,), (1,)), ((), ())))  # (Q, Q)
     y_intra = jax.lax.dot(cb * decay, xdt)              # (Q, P)
     # inter-chunk from carried state
     s_prev = state[...]
-    y_inter = jax.lax.dot(Cm * jnp.exp(cum)[:, None], s_prev)
+    y_inter = jax.lax.dot(Cm * jnp.exp(cum), s_prev)
     # state update
-    sdecay = jnp.exp(total - cum)                       # (Q,)
+    sdecay = jnp.exp(total - cum)                       # (Q, 1)
     s_new = s_prev * jnp.exp(total) + jax.lax.dot_general(
-        Bm * sdecay[:, None], xdt, (((0,), (0,)), ((), ())))   # (N, P)
+        Bm * sdecay, xdt, (((0,), (0,)), ((), ())))     # (N, P)
     state[...] = s_new
-    o_ref[...] = (y_intra + y_inter).reshape(1, 1, Q, 1, P).astype(
-        o_ref.dtype)
+    o_ref[...] = (y_intra + y_inter).reshape(1, 1, Q, P).astype(o_ref.dtype)
 
 
 def ssd_pallas(xh: jnp.ndarray, dt: jnp.ndarray, A: jnp.ndarray,
                Bm: jnp.ndarray, Cm: jnp.ndarray, chunk: int = 128,
-               interpret: bool = True) -> jnp.ndarray:
+               interpret: bool = False) -> jnp.ndarray:
     """SSD forward.  xh: (B, L, H, P); dt: (B, L, H) post-softplus;
     A: (H,) negative; Bm, Cm: (B, L, G, N) with G == 1 (broadcast heads).
 
@@ -77,8 +83,10 @@ def ssd_pallas(xh: jnp.ndarray, dt: jnp.ndarray, A: jnp.ndarray,
     assert G == 1, "kernel broadcasts one B/C group over heads"
     assert L % chunk == 0
     nc, Q = L // chunk, chunk
-    xq = xh.reshape(B, nc, Q, H, P)
-    dtq = dt.reshape(B, nc, Q, H)
+    # heads ahead of (positions, features): every tile's last two dims
+    # are (Q, P), (1, Q) or (Q, N)
+    xq = xh.transpose(0, 2, 1, 3)                       # (B, H, L, P)
+    dtq = dt.transpose(0, 2, 1)[:, :, None, :]          # (B, H, 1, L)
     Bq = Bm.reshape(B, nc, Q, N)
     Cq = Cm.reshape(B, nc, Q, N)
     kernel = functools.partial(_kernel, Q=Q, N=N, P=P)
@@ -86,16 +94,17 @@ def ssd_pallas(xh: jnp.ndarray, dt: jnp.ndarray, A: jnp.ndarray,
         kernel,
         grid=(B, H, nc),                    # chunk LAST: sequential carry
         in_specs=[
-            pl.BlockSpec((1, 1, Q, 1, P), lambda b, h, c: (b, c, 0, h, 0)),
-            pl.BlockSpec((1, 1, Q, 1), lambda b, h, c: (b, c, 0, h)),
-            pl.BlockSpec((1,), lambda b, h, c: (h,)),
+            pl.BlockSpec((1, 1, Q, P), lambda b, h, c: (b, h, c, 0)),
+            pl.BlockSpec((1, 1, 1, Q), lambda b, h, c: (b, h, 0, c)),
+            pl.BlockSpec(memory_space=pltpu.SMEM),
             pl.BlockSpec((1, 1, Q, N), lambda b, h, c: (b, c, 0, 0)),
             pl.BlockSpec((1, 1, Q, N), lambda b, h, c: (b, c, 0, 0)),
         ],
-        out_specs=pl.BlockSpec((1, 1, Q, 1, P),
-                               lambda b, h, c: (b, c, 0, h, 0)),
-        out_shape=jax.ShapeDtypeStruct((B, nc, Q, H, P), xh.dtype),
+        out_specs=pl.BlockSpec((1, 1, Q, P), lambda b, h, c: (b, h, c, 0)),
+        out_shape=jax.ShapeDtypeStruct((B, H, L, P), xh.dtype),
         scratch_shapes=[pltpu.VMEM((N, P), jnp.float32)],
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
     )(xq, dtq, A.astype(jnp.float32), Bq, Cq)
-    return out.reshape(B, L, H, P)
+    return out.transpose(0, 2, 1, 3)

@@ -1,60 +1,48 @@
 """Public jit'd entry points for the Pallas stencil kernel.
 
 ``stencil_apply`` pads the interior up to the block grid, runs the
-Pallas kernel (interpret mode on CPU; compiled on TPU), and slices the
-true interior back out — so arbitrary problem sizes work (the paper's
-"fractional threads" corner case, resolved here by padding geometry
-instead of predication).
+Pallas kernel (compiled for the TPU; ``interpret=True`` runs it in the
+Pallas interpreter, e.g. on the CPU), and slices the true interior back
+out — so arbitrary problem sizes work (the paper's "fractional threads"
+corner case, resolved here by padding geometry instead of predication).
 """
 
 from __future__ import annotations
 
 from typing import Dict, Optional, Tuple
 
-import jax
 import jax.numpy as jnp
-import numpy as np
 
 from repro.core.frontend.stencil import Program
-from .stencil import DEFAULT_BLOCKS, MODES, build_stencil, hbm_bytes_per_block
+from .stencil import (DEFAULT_BLOCKS, MODES, build_stencil,
+                      hbm_bytes_per_block, make_plan)
 from . import ref as stencil_ref
-
-
-def _pad_to_block(x: jnp.ndarray, halo, block) -> Tuple[jnp.ndarray, Tuple[int, ...]]:
-    nd = x.ndim
-    pads = []
-    interior = []
-    for axis in range(nd):
-        d = nd - 1 - axis
-        h = halo[d]
-        n_int = x.shape[axis] - 2 * h
-        b = block[axis]
-        pad = (-n_int) % b
-        pads.append((0, pad))
-        interior.append(n_int)
-    if any(p for _, p in pads):
-        x = jnp.pad(x, pads, mode="edge")
-    return x, tuple(interior)
 
 
 def stencil_apply(prog: Program, arrays: Dict[str, jnp.ndarray],
                   scalars: Optional[Dict[str, float]] = None,
                   mode: str = "tile",
                   block: Optional[Tuple[int, ...]] = None,
-                  interpret: bool = True) -> jnp.ndarray:
-    """Run the stencil program; returns the interior-shaped output."""
+                  interpret: bool = False) -> jnp.ndarray:
+    """Run the stencil program; returns the interior-shaped output.
+
+    Each input is edge-padded on the high side until the interior is a
+    block multiple and every tile-widened fetch window of the last block
+    stays in bounds (``FetchPlan.extent``).
+    """
     assert mode in MODES
     block = tuple(block) if block else DEFAULT_BLOCKS[prog.ndim]
-    halo = prog.halo
-    padded = {}
-    interior = None
-    for name, x in arrays.items():
-        px, it = _pad_to_block(x, halo, block)
-        padded[name] = px
-        interior = it
+    first = next(iter(arrays.values()))
+    interior = stencil_ref.interior_shape(first.shape, prog.halo)
+    grid_interior = tuple(-(-n // b) * b for n, b in zip(interior, block))
+    extent = make_plan(prog, mode).extent(grid_interior, block,
+                                          first.dtype.itemsize)
+    pads = [(0, e - n) for e, n in zip(extent, first.shape)]
+    padded = {name: jnp.pad(x, pads, mode="edge") if any(p for _, p in pads)
+              else x for name, x in arrays.items()}
     fn = build_stencil(prog, mode=mode, block=block, scalars=scalars,
                        interpret=interpret)
-    out = fn(padded)
+    out = fn(padded, grid_interior)
     return out[tuple(slice(0, n) for n in interior)]
 
 

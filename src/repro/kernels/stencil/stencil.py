@@ -21,22 +21,26 @@ Three fetch plans, mirroring the paper's ablation structure:
             every tap a shifted slice in *all* dims (the multi-dim
             generalization the warp cannot express).
 
-The kernel keeps inputs in ``pl.ANY`` (HBM) and stages fetches through
-VMEM scratch explicitly, so the HBM traffic of each plan is visible both
-in the analytic model (:func:`hbm_bytes_per_block`) and in the lowered
-IR.  Correctness is validated in interpret mode against
-:mod:`repro.kernels.stencil.ref` (the pure-jnp oracle).
+The kernel keeps inputs in ``pl.ANY`` (HBM) and stages every fetch
+through VMEM scratch explicitly: one DMA per :class:`Fetch`, its window
+widened to whole (8, 128) memory tiles (:mod:`repro.kernels.dma`), so
+the HBM traffic of each plan is visible both in the analytic model
+(:func:`hbm_bytes_per_block`, which counts the widened windows) and in
+the lowered IR.  Correctness is validated against
+:mod:`repro.kernels.stencil.ref` (the pure-jnp oracle), in interpret mode
+on the CPU and compiled on the chip.
 """
 
 from __future__ import annotations
 
-import functools
+import math
 from dataclasses import dataclass
 from typing import Dict, List, Optional, Sequence, Tuple
 
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 from repro.core.frontend.stencil import (
     Bin,
@@ -48,6 +52,7 @@ from repro.core.frontend.stencil import (
     Scalar,
     collect_loads,
 )
+from ..dma import aligned_window, axis_tiles
 from .ref import _CALLS, tap_offsets
 
 MODES = ("naive", "paper", "tile")
@@ -69,27 +74,48 @@ class Fetch:
     hi: Tuple[int, ...]
     taps: Tuple[Tuple[int, ...], ...]
 
-    def shape(self, block: Sequence[int]) -> Tuple[int, ...]:
-        """VMEM buffer shape, axis order = array order (k, j, i); ``block``
-        is given in the same array-axis order, lo/hi in dim order (i,j,k)."""
+    def window(self, block: Sequence[int], halo: Sequence[int],
+               itemsize: int = 4) -> Tuple[Tuple[int, int], ...]:
+        """The DMA window per array axis (k, j, i), as ``(base, length)``
+        relative to the block's corner in the halo-padded array, widened
+        to whole memory tiles.  ``block`` is in array-axis order; ``halo``,
+        ``lo`` and ``hi`` are in dim order (i, j, k)."""
         nd = len(self.lo)
-        return tuple(block[a] + self.hi[nd - 1 - a] - self.lo[nd - 1 - a]
-                     for a in range(nd))
+        tiles = axis_tiles(nd, itemsize)
+        return tuple(
+            aligned_window(halo[nd - 1 - a] + self.lo[nd - 1 - a],
+                           block[a] + self.hi[nd - 1 - a] - self.lo[nd - 1 - a],
+                           tiles[a])
+            for a in range(nd))
 
 
 @dataclass
 class FetchPlan:
     mode: str
     fetches: List[Fetch]
+    halo: Tuple[int, ...] = ()
+
+    def windows(self, block: Sequence[int], itemsize: int = 4):
+        return [f.window(block, self.halo, itemsize) for f in self.fetches]
 
     def bytes_per_block(self, block: Sequence[int], itemsize: int = 4) -> int:
-        total = 0
-        for f in self.fetches:
-            n = 1
-            for s in f.shape(block):
-                n *= s
-            total += n * itemsize
-        return total
+        """HBM bytes the block's DMAs copy (tile-widened windows)."""
+        return itemsize * sum(math.prod(n for _, n in win)
+                              for win in self.windows(block, itemsize))
+
+    def extent(self, interior: Sequence[int], block: Sequence[int],
+               itemsize: int = 4) -> Tuple[int, ...]:
+        """Array extent per axis that a grid of ``block``-sized output
+        blocks over ``interior`` (a block multiple) may read: the halo'd
+        interior, or further where the last block's widened windows reach
+        past it."""
+        nd = len(block)
+        wins = self.windows(block, itemsize)
+        return tuple(
+            max([interior[a] + 2 * self.halo[nd - 1 - a]]
+                + [interior[a] - block[a] + base + n
+                   for base, n in (w[a] for w in wins)])
+            for a in range(nd))
 
 
 def _unique_taps(prog: Program) -> List[Tuple[str, Tuple[int, ...]]]:
@@ -126,7 +152,7 @@ def make_plan(prog: Program, mode: str) -> FetchPlan:
             lo = tuple(min(o[d] for o in offs) for d in range(nd))
             hi = tuple(max(o[d] for o in offs) for d in range(nd))
             fetches.append(Fetch(arr, lo, hi, tuple(offs)))
-    return FetchPlan(mode, fetches)
+    return FetchPlan(mode, fetches, prog.halo)
 
 
 def hbm_bytes_per_block(prog: Program, mode: str,
@@ -139,39 +165,48 @@ def hbm_bytes_per_block(prog: Program, mode: str,
 # ---------------------------------------------------------------------------
 
 def _build_kernel(prog: Program, plan: FetchPlan, block: Tuple[int, ...],
-                  scalars: Dict[str, float], array_names: List[str]):
+                  scalars: Dict[str, float], array_names: List[str],
+                  itemsize: int):
     nd = prog.ndim
     halo = prog.halo
+    windows = plan.windows(block, itemsize)
+    tiles = axis_tiles(nd, itemsize)
 
     def kernel(*refs):
-        in_refs = dict(zip(array_names, refs[:-1]))
-        out_ref = refs[-1]
-        pids = [pl.program_id(a) for a in range(nd)]        # (gk.., gj, gi)
-        # block start per parallel dim d (i=0 .. k=nd-1), in *array* coords
-        starts = {}
-        for d in range(nd):
-            axis = nd - 1 - d
-            starts[d] = pids[axis] * block[axis] + halo[d]
+        n_in = len(array_names)
+        in_refs = dict(zip(array_names, refs[:n_in]))
+        out_ref = refs[n_in]
+        bufs, sem = refs[n_in + 1:-1], refs[-1]
+        # block corner per array axis (k.., j, i) in the halo-padded array
+        corner = [pl.program_id(a) * block[a] for a in range(nd)]
 
-        # stage fetches: tap offsets -> loaded values
-        tap_val: Dict[Tuple[str, Tuple[int, ...]], jnp.ndarray] = {}
-        for f in plan.fetches:
-            ref = in_refs[f.array]
+        # stage fetches: one tile-aligned DMA per fetch, all in flight
+        copies = []
+        for n, (f, win) in enumerate(zip(plan.fetches, windows)):
             idx = []
-            for axis in range(nd):
-                d = nd - 1 - axis
-                size = block[axis] + f.hi[d] - f.lo[d]
-                idx.append(pl.dslice(starts[d] + f.lo[d], size))
-            buf = ref[tuple(idx)]                          # HBM -> VMEM fetch
+            for a, (base, length) in enumerate(win):
+                start = corner[a] + base
+                if tiles[a] > 1 and block[a] % tiles[a] == 0:
+                    start = pl.multiple_of(start, tiles[a])
+                idx.append(pl.ds(start, length))
+            cp = pltpu.make_async_copy(in_refs[f.array].at[tuple(idx)],
+                                       bufs[n], sem.at[n])
+            cp.start()
+            copies.append(cp)
+        for cp in copies:
+            cp.wait()
+
+        # tap offsets -> loaded values
+        tap_val: Dict[Tuple[str, Tuple[int, ...]], jnp.ndarray] = {}
+        for n, (f, win) in enumerate(zip(plan.fetches, windows)):
             for off in f.taps:
                 sl = []
-                for axis in range(nd):
-                    d = nd - 1 - axis
-                    begin = off[d] - f.lo[d]
-                    sl.append(slice(begin, begin + block[axis]))
+                for a, (base, _) in enumerate(win):
+                    begin = halo[nd - 1 - a] + off[nd - 1 - a] - base
+                    sl.append(slice(begin, begin + block[a]))
                 # static shifted slice of the staged buffer — the TPU
                 # analogue of shfl.sync with delta (off - source)
-                tap_val[(f.array, off)] = buf[tuple(sl)]
+                tap_val[(f.array, off)] = bufs[n][tuple(sl)]
 
         def ev(e: Expr) -> jnp.ndarray:
             if isinstance(e, Load):
@@ -190,17 +225,19 @@ def _build_kernel(prog: Program, plan: FetchPlan, block: Tuple[int, ...],
 
         out_ref[...] = ev(prog.expr).astype(out_ref.dtype)
 
-    return kernel
+    return kernel, windows
 
 
 def build_stencil(prog: Program, mode: str = "tile",
                   block: Optional[Tuple[int, ...]] = None,
                   scalars: Optional[Dict[str, float]] = None,
-                  interpret: bool = True):
-    """Build a callable ``f(arrays: dict) -> interior output`` running the
-    stencil as a Pallas kernel with the given fetch plan.
+                  interpret: bool = False):
+    """Build a callable ``f(arrays: dict, interior) -> output`` running
+    the stencil as a Pallas kernel with the given fetch plan.
 
-    Interior sizes (shape - 2*halo per dim) must divide the block; use
+    ``interior`` is the output shape, a multiple of the block per axis;
+    each array must extend to at least ``plan.extent(interior, block)``
+    so that every widened DMA window stays in bounds.  Use
     :func:`repro.kernels.stencil.ops.stencil_apply` for auto-padding.
     """
     assert mode in MODES
@@ -209,27 +246,32 @@ def build_stencil(prog: Program, mode: str = "tile",
     plan = make_plan(prog, mode)
     scalars = dict(scalars or {})
     array_names = sorted(a for a in prog.arrays if a != prog.out.array)
-    kernel = _build_kernel(prog, plan, block, scalars, array_names)
     nd = prog.ndim
-    halo = prog.halo
 
-    def apply_fn(arrays: Dict[str, jnp.ndarray]) -> jnp.ndarray:
-        shape = arrays[array_names[0]].shape
-        interior = tuple(shape[a] - 2 * halo[nd - 1 - a] for a in range(nd))
-        grid = tuple(interior[a] // block[a] for a in range(nd))
-        for a in range(nd):
-            if interior[a] % block[a]:
-                raise ValueError(
-                    f"interior {interior} not divisible by block {block}")
-        in_specs = [pl.BlockSpec(memory_space=pl.ANY)
-                    for _ in array_names]
-        out_spec = pl.BlockSpec(block, lambda *p: p)
+    def apply_fn(arrays: Dict[str, jnp.ndarray],
+                 interior: Tuple[int, ...]) -> jnp.ndarray:
+        first = arrays[array_names[0]]
+        itemsize = first.dtype.itemsize
+        if any(interior[a] % block[a] for a in range(nd)):
+            raise ValueError(
+                f"interior {interior} not divisible by block {block}")
+        need = plan.extent(interior, block, itemsize)
+        if any(first.shape[a] < need[a] for a in range(nd)):
+            raise ValueError(f"arrays of shape {first.shape} are smaller "
+                             f"than the {need} the fetch windows read")
+        kernel, windows = _build_kernel(prog, plan, block, scalars,
+                                        array_names, itemsize)
+        scratch = [pltpu.VMEM(tuple(n for _, n in win), first.dtype)
+                   for win in windows]
+        scratch.append(pltpu.SemaphoreType.DMA((len(windows),)))
         fn = pl.pallas_call(
             kernel,
-            grid=grid,
-            in_specs=in_specs,
-            out_specs=out_spec,
+            grid=tuple(interior[a] // block[a] for a in range(nd)),
+            in_specs=[pl.BlockSpec(memory_space=pl.ANY)
+                      for _ in array_names],
+            out_specs=pl.BlockSpec(block, lambda *p: p),
             out_shape=jax.ShapeDtypeStruct(interior, jnp.float32),
+            scratch_shapes=scratch,
             interpret=interpret,
         )
         return fn(*[arrays[a] for a in array_names])

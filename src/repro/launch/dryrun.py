@@ -1,5 +1,9 @@
 import os
-os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=512"
+# 512 virtual host devices stand in for the production mesh; pin the CPU
+# platform so the dry-run never takes an attached accelerator
+os.environ["JAX_PLATFORMS"] = "cpu"
+os.environ["XLA_FLAGS"] = (os.environ.get("XLA_FLAGS", "") +
+                           " --xla_force_host_platform_device_count=512")
 
 """Multi-pod dry-run: lower + compile every (arch x shape x mesh) cell.
 

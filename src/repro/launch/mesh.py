@@ -12,26 +12,18 @@ from __future__ import annotations
 from typing import Optional, Tuple
 
 import jax
-
-try:                                  # jax >= 0.5: explicit axis types
-    from jax.sharding import AxisType
-
-    def _axis_kw(n: int) -> dict:
-        return {"axis_types": (AxisType.Auto,) * n}
-except ImportError:                   # older jax: Auto is the only behaviour
-    def _axis_kw(n: int) -> dict:
-        return {}
+from jax.sharding import AxisType
 
 
 def make_production_mesh(*, multi_pod: bool = False):
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes, **_axis_kw(len(axes)))
+    return make_mesh(shape, axes)
 
 
 def make_mesh(shape: Tuple[int, ...], axes: Tuple[str, ...]):
     """Arbitrary mesh (tests, laptop-scale runs)."""
-    return jax.make_mesh(shape, axes, **_axis_kw(len(axes)))
+    return jax.make_mesh(shape, axes, axis_types=(AxisType.Auto,) * len(axes))
 
 
 def make_host_mesh():

@@ -19,10 +19,11 @@ import numpy as np
 from repro.configs import get_config, reduced
 from repro.data import DataConfig, TokenPipeline
 from repro.models import build_model, unbox
+from repro.runtime import enable_compile_cache
 from repro.serve import generate
 
 
-def main(argv=None) -> dict:
+def parse_args(argv=None) -> argparse.Namespace:
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default="yi-9b")
     ap.add_argument("--reduced", action="store_true")
@@ -30,13 +31,17 @@ def main(argv=None) -> dict:
     ap.add_argument("--prompt-len", type=int, default=32)
     ap.add_argument("--gen", type=int, default=16)
     ap.add_argument("--temperature", type=float, default=0.0)
-    args = ap.parse_args(argv)
+    return ap.parse_args(argv)
 
+
+def build(args: argparse.Namespace):
+    """The served model, its seeded parameters and the prompt batch:
+    ``(cfg, model, params, batch)``, the same for the same ``args``."""
     cfg = get_config(args.arch)
     if args.reduced:
         cfg = reduced(cfg)
     model = build_model(cfg)
-    params = unbox(model.init(jax.random.PRNGKey(0)))
+    params = jax.jit(lambda k: unbox(model.init(k)))(jax.random.PRNGKey(0))
 
     pipe = TokenPipeline(DataConfig(vocab=cfg.vocab, seq_len=args.prompt_len,
                                     global_batch=args.batch))
@@ -48,6 +53,13 @@ def main(argv=None) -> dict:
     if cfg.family == "audio":
         batch["frames"] = jnp.zeros(
             (args.batch, cfg.n_frames, cfg.d_model), jnp.float32)
+    return cfg, model, params, batch
+
+
+def main(argv=None) -> dict:
+    args = parse_args(argv)
+    enable_compile_cache()
+    cfg, model, params, batch = build(args)
 
     t0 = time.time()
     out = generate(model, params, batch, n_tokens=args.gen,

@@ -21,16 +21,17 @@ from typing import Optional
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.sharding import NamedSharding, PartitionSpec as P
 
 from repro.checkpoint import CheckpointStore
 from repro.configs import get_config, reduced
 from repro.data import DataConfig, TokenPipeline
 from repro.launch.mesh import make_mesh
 from repro.models import build_model, unbox
-from repro.models.common import LogicalArray
-from repro.runtime import Heartbeat, StragglerDetector
+from repro.runtime import Heartbeat, StragglerDetector, enable_compile_cache
 from repro.sharding import batch_sharding, param_shardings
-from repro.train import OptConfig, init_opt_state, make_train_step
+from repro.sharding.rules import rules_for
+from repro.train import OptConfig, OptState, init_opt_state, make_train_step
 
 
 def main(argv=None) -> dict:
@@ -49,6 +50,7 @@ def main(argv=None) -> dict:
     ap.add_argument("--accum", type=int, default=1)
     ap.add_argument("--log-every", type=int, default=10)
     args = ap.parse_args(argv)
+    enable_compile_cache()
 
     cfg = get_config(args.arch)
     if args.reduced:
@@ -58,15 +60,15 @@ def main(argv=None) -> dict:
     model = build_model(cfg, mesh if d * m > 1 else None)
 
     boxed = jax.eval_shape(model.init, jax.random.PRNGKey(0))
-    shardings = param_shardings(boxed, mesh)
-    params = jax.jit(
-        lambda k: unbox(model.init(k)),
-        out_shardings=jax.tree_util.tree_map(
-            lambda x: x, shardings,
-            is_leaf=lambda x: hasattr(x, "spec")))(jax.random.PRNGKey(0))
+    shardings = param_shardings(boxed, mesh, rules=rules_for(cfg, mesh))
+    params = jax.jit(lambda k: unbox(model.init(k)),
+                     out_shardings=shardings)(jax.random.PRNGKey(0))
     opt_cfg = OptConfig(lr=args.lr, warmup_steps=max(args.steps // 20, 5),
                         total_steps=args.steps)
-    opt_state = init_opt_state(params)
+    # built on the devices, each moment sharded like its parameter
+    opt_state = jax.jit(init_opt_state, out_shardings=OptState(
+        mu=shardings, nu=shardings,
+        count=NamedSharding(mesh, P())))(params)
 
     pipe = TokenPipeline(DataConfig(vocab=cfg.vocab, seq_len=args.seq,
                                     global_batch=args.batch))
@@ -119,7 +121,24 @@ def main(argv=None) -> dict:
     print(f"[done] {args.steps - start_step} steps in {wall:.1f}s; "
           f"loss {losses[0]:.4f} -> {losses[-1]:.4f}")
     return {"first_loss": losses[0], "last_loss": losses[-1],
-            "steps": args.steps, "wall_s": wall}
+            "losses": losses, "steps": args.steps, "wall_s": wall,
+            "memory": device_memory(mesh, (params, opt_state))}
+
+
+def device_memory(mesh, state) -> list:
+    """Per mesh device: the bytes of ``state`` it holds, and the
+    backend's own counters where it reports them."""
+    held = {d: 0 for d in mesh.devices.flat}
+    for leaf in jax.tree_util.tree_leaves(state):
+        for shard in leaf.addressable_shards:
+            held[shard.device] += shard.data.nbytes
+    out = []
+    for d, n in held.items():
+        stats = d.memory_stats() or {}
+        out.append({"device": str(d), "state_bytes": n,
+                    "bytes_in_use": stats.get("bytes_in_use"),
+                    "peak_bytes_in_use": stats.get("peak_bytes_in_use")})
+    return out
 
 
 if __name__ == "__main__":

@@ -41,7 +41,8 @@ def test_stencil_matches_oracle(name, mode):
     scalars = {s: float(RNG.uniform(0.1, 1.0)) for s in prog.scalars}
     ref = reference(prog, arrays, scalars)
     out = stencil_apply(prog, arrays, scalars, mode=mode,
-                        block={1: (64,), 2: (8, 32), 3: (1, 8, 32)}[nd])
+                        block={1: (64,), 2: (8, 32), 3: (1, 8, 32)}[nd],
+                        interpret=True)
     np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
                                rtol=2e-4, atol=2e-4)
 
@@ -80,7 +81,8 @@ def test_conv1d_matches_oracle(shape, dtype, mode):
     w = jnp.asarray(RNG.standard_normal((W, C)), dtype)
     b = jnp.asarray(RNG.standard_normal((C,)), dtype)
     ref = conv_ref.causal_conv1d(x, w, b)
-    out = causal_conv1d(x, w, b, mode=mode, block_seq=32, block_ch=16)
+    out = causal_conv1d(x, w, b, mode=mode, block_seq=32, block_ch=16,
+                        interpret=True)
     tol = 1e-5 if dtype == jnp.float32 else 5e-2
     np.testing.assert_allclose(np.asarray(out, np.float32),
                                np.asarray(ref, np.float32),
@@ -121,7 +123,8 @@ def test_flash_attention_matches_oracle(shape, dtype):
     k = jnp.asarray(RNG.standard_normal((B, Sk, KV, Dh)), dtype)
     v = jnp.asarray(RNG.standard_normal((B, Sk, KV, Dh)), dtype)
     ref = attention_ref(q, k, v, causal=causal)
-    out = flash_attention(q, k, v, causal=causal, block_q=16, block_k=16)
+    out = flash_attention(q, k, v, causal=causal, block_q=16, block_k=16,
+                          interpret=True)
     tol = 2e-5 if dtype == jnp.float32 else 6e-2
     np.testing.assert_allclose(np.asarray(out, np.float32),
                                np.asarray(ref, np.float32),
@@ -137,6 +140,7 @@ def test_flash_attention_property(B, S, KV, Dh):
     k = jnp.asarray(RNG.standard_normal((B, S, KV, Dh)), jnp.float32)
     v = jnp.asarray(RNG.standard_normal((B, S, KV, Dh)), jnp.float32)
     ref = attention_ref(q, k, v, causal=True)
-    out = flash_attention(q, k, v, causal=True, block_q=16, block_k=16)
+    out = flash_attention(q, k, v, causal=True, block_q=16, block_k=16,
+                          interpret=True)
     np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
                                rtol=3e-5, atol=3e-5)

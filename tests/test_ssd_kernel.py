@@ -29,7 +29,7 @@ def test_ssd_pallas_matches_oracle(shape, dtype):
     B, L, H, P, N, Q = shape
     xh, dt, A, Bm, Cm = _inputs(B, L, H, P, N, dtype)
     ref = ssd_ref(xh, dt, A, Bm, Cm, chunk=Q)
-    out = ssd_pallas(xh, dt, A, Bm, Cm, chunk=Q)
+    out = ssd_pallas(xh, dt, A, Bm, Cm, chunk=Q, interpret=True)
     tol = 1e-4 if dtype == jnp.float32 else 8e-2
     np.testing.assert_allclose(np.asarray(out, np.float32),
                                np.asarray(ref, np.float32),
@@ -40,7 +40,7 @@ def test_ssd_state_carries_across_chunks():
     """Single long chunk == many short chunks (scratch carry exactness)."""
     B, L, H, P, N = 1, 64, 2, 8, 16
     xh, dt, A, Bm, Cm = _inputs(B, L, H, P, N, jnp.float32)
-    one = ssd_pallas(xh, dt, A, Bm, Cm, chunk=64)
-    many = ssd_pallas(xh, dt, A, Bm, Cm, chunk=8)
+    one = ssd_pallas(xh, dt, A, Bm, Cm, chunk=64, interpret=True)
+    many = ssd_pallas(xh, dt, A, Bm, Cm, chunk=8, interpret=True)
     np.testing.assert_allclose(np.asarray(one), np.asarray(many),
                                rtol=2e-4, atol=2e-4)
