@@ -23,12 +23,15 @@ def stencil_apply(prog: Program, arrays: Dict[str, jnp.ndarray],
                   scalars: Optional[Dict[str, float]] = None,
                   mode: str = "tile",
                   block: Optional[Tuple[int, ...]] = None,
-                  interpret: bool = False) -> jnp.ndarray:
+                  interpret: bool = False,
+                  trace_every: int = 0) -> jnp.ndarray:
     """Run the stencil program; returns the interior-shaped output.
 
     Each input is edge-padded on the high side until the interior is a
     block multiple and every tile-widened fetch window of the last block
-    stays in bounds (``FetchPlan.extent``).
+    stays in bounds (``FetchPlan.extent``).  ``trace_every`` > 0 makes
+    every that-many-th grid step record the kernel's trace regions
+    (:func:`build_stencil`).
     """
     assert mode in MODES
     block = tuple(block) if block else DEFAULT_BLOCKS[prog.ndim]
@@ -41,7 +44,7 @@ def stencil_apply(prog: Program, arrays: Dict[str, jnp.ndarray],
     padded = {name: jnp.pad(x, pads, mode="edge") if any(p for _, p in pads)
               else x for name, x in arrays.items()}
     fn = build_stencil(prog, mode=mode, block=block, scalars=scalars,
-                       interpret=interpret)
+                       interpret=interpret, trace_every=trace_every)
     out = fn(padded, grid_interior)
     return out[tuple(slice(0, n) for n in interior)]
 

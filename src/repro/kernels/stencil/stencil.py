@@ -33,6 +33,7 @@ on the CPU and compiled on the chip.
 
 from __future__ import annotations
 
+import contextlib
 import math
 from dataclasses import dataclass
 from typing import Dict, List, Optional, Sequence, Tuple
@@ -58,6 +59,9 @@ from .ref import _CALLS, tap_offsets
 MODES = ("naive", "paper", "tile")
 
 DEFAULT_BLOCKS = {1: (256,), 2: (8, 128), 3: (1, 8, 128)}
+
+# the trace regions of a sampled grid step, in the order they run
+REGIONS = ("stencil_dma_issue", "stencil_dma_wait", "stencil_compute")
 
 
 # ---------------------------------------------------------------------------
@@ -164,37 +168,42 @@ def hbm_bytes_per_block(prog: Program, mode: str,
 # kernel construction
 # ---------------------------------------------------------------------------
 
+def _no_scope(name: str):
+    return contextlib.nullcontext()
+
+
 def _build_kernel(prog: Program, plan: FetchPlan, block: Tuple[int, ...],
                   scalars: Dict[str, float], array_names: List[str],
-                  itemsize: int):
+                  itemsize: int, trace_every: int = 0):
     nd = prog.ndim
     halo = prog.halo
     windows = plan.windows(block, itemsize)
     tiles = axis_tiles(nd, itemsize)
+    issue_region, wait_region, compute_region = REGIONS
 
-    def kernel(*refs):
+    def step(refs, corner, scope):
         n_in = len(array_names)
         in_refs = dict(zip(array_names, refs[:n_in]))
         out_ref = refs[n_in]
         bufs, sem = refs[n_in + 1:-1], refs[-1]
-        # block corner per array axis (k.., j, i) in the halo-padded array
-        corner = [pl.program_id(a) * block[a] for a in range(nd)]
 
         # stage fetches: one tile-aligned DMA per fetch, all in flight
         copies = []
-        for n, (f, win) in enumerate(zip(plan.fetches, windows)):
-            idx = []
-            for a, (base, length) in enumerate(win):
-                start = corner[a] + base
-                if tiles[a] > 1 and block[a] % tiles[a] == 0:
-                    start = pl.multiple_of(start, tiles[a])
-                idx.append(pl.ds(start, length))
-            cp = pltpu.make_async_copy(in_refs[f.array].at[tuple(idx)],
-                                       bufs[n], sem.at[n])
-            cp.start()
-            copies.append(cp)
-        for cp in copies:
-            cp.wait()
+        with scope(issue_region):
+            for n, (f, win) in enumerate(zip(plan.fetches, windows)):
+                idx = []
+                for a, (base, length) in enumerate(win):
+                    start = corner[a] + base
+                    if tiles[a] > 1 and block[a] % tiles[a] == 0:
+                        start = pl.multiple_of(start, tiles[a])
+                    idx.append(pl.ds(start, length))
+                cp = pltpu.make_async_copy(in_refs[f.array].at[tuple(idx)],
+                                           bufs[n], sem.at[n])
+                cp.start()
+                copies.append(cp)
+        with scope(wait_region):
+            for cp in copies:
+                cp.wait()
 
         # tap offsets -> loaded values
         tap_val: Dict[Tuple[str, Tuple[int, ...]], jnp.ndarray] = {}
@@ -223,7 +232,24 @@ def _build_kernel(prog: Program, plan: FetchPlan, block: Tuple[int, ...],
                 return _CALLS[e.fn](ev(e.arg))
             raise TypeError(e)
 
-        out_ref[...] = ev(prog.expr).astype(out_ref.dtype)
+        with scope(compute_region):
+            out_ref[...] = ev(prog.expr).astype(out_ref.dtype)
+
+    def kernel(*refs):
+        # block corner per array axis (k.., j, i) in the halo-padded array
+        corner = [pl.program_id(a) * block[a] for a in range(nd)]
+        if not trace_every:
+            step(refs, corner, _no_scope)
+            return
+        # grid steps whose linear index is a multiple of trace_every
+        # record REGIONS; the others run the same work unrecorded
+        linear = pl.program_id(0)
+        for a in range(1, nd):
+            linear = linear * pl.num_programs(a) + pl.program_id(a)
+        sampled = linear % trace_every == 0
+        pl.when(sampled)(lambda: step(refs, corner, jax.named_scope))
+        pl.when(jnp.logical_not(sampled))(
+            lambda: step(refs, corner, _no_scope))
 
     return kernel, windows
 
@@ -231,7 +257,7 @@ def _build_kernel(prog: Program, plan: FetchPlan, block: Tuple[int, ...],
 def build_stencil(prog: Program, mode: str = "tile",
                   block: Optional[Tuple[int, ...]] = None,
                   scalars: Optional[Dict[str, float]] = None,
-                  interpret: bool = False):
+                  interpret: bool = False, trace_every: int = 0):
     """Build a callable ``f(arrays: dict, interior) -> output`` running
     the stencil as a Pallas kernel with the given fetch plan.
 
@@ -239,8 +265,15 @@ def build_stencil(prog: Program, mode: str = "tile",
     each array must extend to at least ``plan.extent(interior, block)``
     so that every widened DMA window stays in bounds.  Use
     :func:`repro.kernels.stencil.ops.stencil_apply` for auto-padding.
+
+    With ``trace_every`` N > 0, grid steps whose linear index is a
+    multiple of N record the named trace regions :data:`REGIONS` (DMA
+    issue, DMA wait, compute), which a device profile shows where the
+    compiler is asked for custom-call region traces; 0 builds the kernel
+    without any trace op.
     """
     assert mode in MODES
+    assert trace_every >= 0
     block = tuple(block) if block else DEFAULT_BLOCKS[prog.ndim]
     assert len(block) == prog.ndim
     plan = make_plan(prog, mode)
@@ -260,7 +293,7 @@ def build_stencil(prog: Program, mode: str = "tile",
             raise ValueError(f"arrays of shape {first.shape} are smaller "
                              f"than the {need} the fetch windows read")
         kernel, windows = _build_kernel(prog, plan, block, scalars,
-                                        array_names, itemsize)
+                                        array_names, itemsize, trace_every)
         scratch = [pltpu.VMEM(tuple(n for _, n in win), first.dtype)
                    for win in windows]
         scratch.append(pltpu.SemaphoreType.DMA((len(windows),)))

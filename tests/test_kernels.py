@@ -47,6 +47,25 @@ def test_stencil_matches_oracle(name, mode):
                                rtol=2e-4, atol=2e-4)
 
 
+@pytest.mark.parametrize("name", ["jacobi", "tricubic"])
+def test_stencil_with_trace_regions_matches_oracle(name):
+    """Every third grid step records the kernel's trace regions; the
+    output still equals the oracle, and the plain kernel's bit for bit."""
+    prog = get_bench(name).program
+    shape = {2: (20, 140), 3: (6, 20, 140)}[prog.ndim]
+    arrays = {a: jnp.asarray(RNG.standard_normal(shape), jnp.float32)
+              for a in prog.arrays if a != prog.out.array}
+    scalars = {s: float(RNG.uniform(0.1, 1.0)) for s in prog.scalars}
+    block = {2: (8, 32), 3: (1, 8, 32)}[prog.ndim]
+    out, plain = (stencil_apply(prog, arrays, scalars, mode="paper",
+                                block=block, interpret=True, trace_every=n)
+                  for n in (3, 0))
+    np.testing.assert_allclose(np.asarray(out),
+                               np.asarray(reference(prog, arrays, scalars)),
+                               rtol=2e-4, atol=2e-4)
+    np.testing.assert_array_equal(np.asarray(out), np.asarray(plain))
+
+
 @pytest.mark.parametrize("name", sorted(all_benches(include_apps=True)))
 def test_detection_plan_consistency(name):
     """The symbolic emulator's shuffle count must equal the geometric

@@ -7,6 +7,8 @@ The topology is described only inside a fixture: loading the TPU
 compiler takes a process-wide lock, so no module may do it at import.
 """
 
+import base64
+import json
 import os
 
 import jax
@@ -66,6 +68,55 @@ def test_stencil_compiles(name, mode, one_chip):
 
     text = _compile_text(fn, one_chip, *[(shape, jnp.float32)] * len(names))
     assert "tpu_custom_call" in text
+
+
+def _kernel_bodies(lowered):
+    """The serialized Mosaic module of each TPU custom call."""
+    from jax._src.lib.mlir import ir
+    bodies = []
+
+    def walk(op):
+        for region in op.regions:
+            for block in region.blocks:
+                for inner in block.operations:
+                    if inner.operation.name == "stablehlo.custom_call":
+                        config = ir.StringAttr(
+                            inner.attributes["backend_config"]).value
+                        bodies.append(base64.b64decode(json.loads(
+                            config)["custom_call_config"]["body"]))
+                    walk(inner.operation)
+    walk(lowered.compiler_ir("stablehlo").operation)
+    return bodies
+
+
+@pytest.mark.parametrize("trace_every", [0, 251])
+@pytest.mark.parametrize("name", ["jacobi", "tricubic"])
+def test_stencil_trace_regions(name, trace_every, one_chip):
+    """The default kernel holds no trace op; a sampling one holds the
+    three named regions and compiles with custom-call region traces on."""
+    from repro.kernels.stencil import REGIONS
+    prog = get_bench(name).program
+    names = sorted(a for a in prog.arrays if a != prog.out.array)
+    scalars = {s: 0.5 for s in prog.scalars}
+    shape = STENCIL_SHAPES[prog.ndim]
+
+    def fn(*xs):
+        return stencil_apply(prog, dict(zip(names, xs)), scalars,
+                             mode="paper", trace_every=trace_every)
+
+    args = [jax.ShapeDtypeStruct(shape, jnp.float32, sharding=one_chip)
+            for _ in names]
+    lowered = jax.jit(fn).lower(*args)
+    (body,) = _kernel_bodies(lowered)
+    if trace_every == 0:
+        assert b"trace_start" not in body
+        assert not any(r.encode() in body for r in REGIONS)
+    else:
+        assert b"trace_start" in body and b"trace_stop" in body
+        assert all(r.encode() in body for r in REGIONS)
+        text = lowered.compile(compiler_options={
+            "xla_enable_custom_call_region_trace": True}).as_text()
+        assert "tpu_custom_call" in text
 
 
 @pytest.mark.parametrize("mode", ["naive", "shuffle"])
