@@ -66,6 +66,70 @@ def test_stencil_with_trace_regions_matches_oracle(name):
     np.testing.assert_array_equal(np.asarray(out), np.asarray(plain))
 
 
+# (program, interior, block): grids whose fetch ring crosses rows (4x4
+# steps), rows and planes (2x2x3), and grids shorter than the ring
+RING_GRIDS = {
+    "rows": ("jacobi", (32, 128), (8, 32)),
+    "planes": ("tricubic", (2, 16, 96), (1, 8, 32)),
+    "one_step": ("gaussblur", (8, 32), (8, 32)),
+    "two_steps": ("laplacian", (2, 8, 32), (1, 8, 32)),
+}
+
+
+def _check_ring(grid, mode):
+    """The kernel's output on one of RING_GRIDS equals the oracle's, and
+    a region-sampling build's (every other step) equals it bit for bit."""
+    name, interior, block = RING_GRIDS[grid]
+    prog = get_bench(name).program
+    nd = prog.ndim
+    shape = tuple(n + 2 * prog.halo[nd - 1 - a]
+                  for a, n in enumerate(interior))
+    arrays = {a: jnp.asarray(RNG.standard_normal(shape[-d:]), jnp.float32)
+              for a, d in prog.arrays.items() if a != prog.out.array}
+    scalars = {s: float(RNG.uniform(0.1, 1.0)) for s in prog.scalars}
+    out, plain = (stencil_apply(prog, arrays, scalars, mode=mode,
+                                block=block, interpret=True, trace_every=n)
+                  for n in (2, 0))
+    np.testing.assert_allclose(np.asarray(out),
+                               np.asarray(reference(prog, arrays, scalars)),
+                               rtol=2e-4, atol=2e-4)
+    np.testing.assert_array_equal(np.asarray(out), np.asarray(plain))
+
+
+@pytest.mark.parametrize("mode", ["naive", "paper", "tile"])
+@pytest.mark.parametrize("grid", sorted(RING_GRIDS))
+def test_stencil_fetch_ring_matches_oracle(grid, mode):
+    _check_ring(grid, mode)
+
+
+@pytest.mark.parametrize("grid", sorted(RING_GRIDS))
+def test_stencil_fetch_ring_fallback_depth(grid, monkeypatch):
+    """The depth-2 ring that a block past the VMEM budget gets."""
+    from repro.kernels.stencil import stencil as stencil_mod
+    monkeypatch.setattr(stencil_mod, "VMEM_BUDGET", 0)
+    _check_ring(grid, "paper")
+
+
+def test_ring_depth_rule():
+    """RING_DEPTH slots while they and the output's two blocks fit the
+    VMEM budget, 2 past it; every default block of every plan fits."""
+    from repro.kernels.stencil import DEFAULT_BLOCKS, hbm_bytes_per_block
+    from repro.kernels.stencil.stencil import (RING_DEPTH, VMEM_BUDGET,
+                                               ring_depth)
+    out_block = 4 * 8 * 128
+    fits = (VMEM_BUDGET - 2 * out_block) // RING_DEPTH
+    assert ring_depth(0, out_block) == RING_DEPTH
+    assert ring_depth(fits, out_block) == RING_DEPTH
+    assert ring_depth(fits + 1, out_block) == 2
+    assert ring_depth(VMEM_BUDGET, out_block) == 2
+    for name in STENCIL_BENCHES:
+        prog = get_bench(name).program
+        block = DEFAULT_BLOCKS[prog.ndim]
+        for mode in ("naive", "paper", "tile"):
+            assert ring_depth(hbm_bytes_per_block(prog, mode, block),
+                              4 * np.prod(block)) == RING_DEPTH
+
+
 @pytest.mark.parametrize("name", sorted(all_benches(include_apps=True)))
 def test_detection_plan_consistency(name):
     """The symbolic emulator's shuffle count must equal the geometric
