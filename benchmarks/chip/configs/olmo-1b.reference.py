@@ -25,6 +25,7 @@ from __future__ import annotations
 
 import functools
 import math
+import time
 from typing import Dict, Optional
 
 import jax
@@ -216,7 +217,7 @@ def _adamw(p, g, m, v, scale, lr, b1c, b2c, opt):
 
 
 def train_steps(key, cfg: Dict, opt: Dict, batches, quant=None,
-                half_batch=False):
+                half_batch=False, grad_against=None, keep_grad=False):
     """Three (or ``len(batches)``) AdamW steps from the seeded weights.
 
     Returns the loss of each step, the norm of each leaf of the first
@@ -226,6 +227,13 @@ def train_steps(key, cfg: Dict, opt: Dict, batches, quant=None,
     CPU device, since weights, gradients and both moments in float32 do
     not fit one chip together.  ``half_batch`` leaves the second half of
     each batch's tokens out of the loss (a fault the check must catch).
+
+    ``seconds`` gives the time of the gradients (on the default device,
+    with their compile) and of the rest (the host's share).
+    ``grad_against`` (leaf name to a float32 array on the host) adds
+    ``grad_diff_norms``: per leaf, the norm of this first gradient less
+    that one.  ``keep_grad`` adds ``first_grad``, this first gradient's
+    arrays on the host's CPU device.
     """
     cpu = jax.devices("cpu")[0]
     w = jax.jit(lambda k: init_weights(k, cfg))(key)
@@ -239,14 +247,22 @@ def train_steps(key, cfg: Dict, opt: Dict, batches, quant=None,
 
     adamw = jax.jit(functools.partial(_adamw, opt=opt))
     sq = jax.jit(lambda x: jnp.sum(jnp.square(x.astype(jnp.float32))))
+    diff_sq = jax.jit(lambda a, b: jnp.sum(jnp.square(a - b)))
+    scaled = jax.jit(lambda a, s: a * s)
     p_host = {n: jax.device_put(a, cpu) for n, a in w.items()}
     p0 = dict(p_host)
-    m = {n: jnp.zeros(a.shape, jnp.float32, device=cpu) for n, a in w.items()}
+    # both moments start at zero, held as scalars until the first step
+    # writes them: the host then holds no weight-sized zeros
+    m = dict.fromkeys(LEAVES, 0.0)
     v = dict(m)
-    losses, first_grad = [], {}
+    losses, first_grad, out = [], {}, {}
+    seconds = {"gradients": 0.0, "host": 0.0}
     for t, (tokens, labels) in enumerate(batches, start=1):
+        t0 = time.perf_counter()
         lval, g = grad_fn(w, jnp.asarray(tokens), jnp.asarray(labels))
         losses.append(float(lval))
+        t1 = time.perf_counter()
+        seconds["gradients"] += t1 - t0
         del w
         g = {n: jax.device_put(a, cpu) for n, a in g.items()}
         gnorm = math.sqrt(sum(float(sq(a)) for a in g.values()))
@@ -254,6 +270,13 @@ def train_steps(key, cfg: Dict, opt: Dict, batches, quant=None,
         if t == 1:
             first_grad = {n: math.sqrt(float(sq(a))) * scale
                           for n, a in g.items()}
+            if grad_against is not None:
+                out["grad_diff_norms"] = {
+                    n: math.sqrt(float(diff_sq(
+                        scaled(a, scale), jax.device_put(grad_against[n], cpu))))
+                    for n, a in g.items()}
+            if keep_grad:
+                out["first_grad"] = {n: scaled(a, scale) for n, a in g.items()}
         lr = lr_at(opt, t)
         b1c, b2c = 1 - opt["b1"] ** t, 1 - opt["b2"] ** t
         for n in LEAVES:
@@ -261,7 +284,10 @@ def train_steps(key, cfg: Dict, opt: Dict, batches, quant=None,
                                           lr, b1c, b2c)
         del g
         w = {n: jax.device_put(a, jax.devices()[0]) for n, a in p_host.items()}
+        jax.block_until_ready(w)
+        seconds["host"] += time.perf_counter() - t1
     change = {n: math.sqrt(float(sq(p_host[n].astype(jnp.float32)
                                     - p0[n].astype(jnp.float32))))
               for n in LEAVES}
-    return {"losses": losses, "grad_norms": first_grad, "change_norms": change}
+    return {"losses": losses, "grad_norms": first_grad, "change_norms": change,
+            "seconds": seconds, **out}

@@ -16,7 +16,8 @@ batch per round; weights from the configuration's reference
 Set-up compiles both steps, prefills the first round and runs one decode
 step whose result it drops.  After the window a round still open is
 finished untimed if no round finished inside it; then requests drawn
-from the seed out of the last finished round are run through the
+from the seed out of the last finished round, one from each of
+``check_requests`` equal stretches of its batch, are run through the
 reference, and the widest gap by which a served token's reference logit
 lies below the reference's best is compared with its limit.
 """
@@ -51,7 +52,7 @@ class Driver:
         self.ref = ctx.reference()
         self.key = seed_key(ctx.seed)
         model = build_model(model_config(cfg))
-        with span("weights"):
+        with ctx.part("weights"):
             weights = jax.jit(functools.partial(self.ref.init_weights, cfg=cfg))(
                 self.key)
             self.params = program_params(model, weights)
@@ -65,8 +66,9 @@ class Driver:
         self.round = -1
         self.finished = []                  # (prompts, served (B, G))
         self.w0 = float("inf")
-        self._start_round()
-        with span("warmup"):
+        with ctx.part("first_prefill"):
+            self._start_round()
+        with ctx.part("warmup"):
             warm, _ = self.decode(self.params, self.tok, self.cache, self.rng)
             np.asarray(warm)
         self.begin()
@@ -122,7 +124,7 @@ class Driver:
         return {
             # requests: each round the window touched serves a batch
             "attempted": self.B * self.rounds_touched,
-            "checked": self.ctx.traffic["check_requests"],
+            "checked": len(self._check_rows()),
             "units": self.units,
             "end_to_end": {
                 "decode_tokens_s": self.tokens / window_s,
@@ -141,12 +143,19 @@ class Driver:
         self.params = self.cache = self.tok = None
         self.prefill = self.decode = None
 
+    def _check_rows(self):
+        """``check_requests`` rows of the batch drawn from the seed, one
+        from each of as many equal stretches of the batch."""
+        rng = np.random.default_rng(self.ctx.seed)
+        ends = np.linspace(0, self.B, self.ctx.traffic["check_requests"] + 1)
+        ends = ends.astype(int)
+        return [int(rng.integers(lo, hi))
+                for lo, hi in zip(ends[:-1], ends[1:]) if hi > lo]
+
     def _gaps(self, quant=None):
         cfg = self.ctx.config
         prompts, served = self.finished[-1]
-        rng = np.random.default_rng(self.ctx.seed)
-        rows = rng.choice(self.B, size=self.ctx.traffic["check_requests"],
-                          replace=False)
+        rows = self._check_rows()
         w = jax.jit(functools.partial(self.ref.init_weights, cfg=cfg))(self.key)
         gap_fn = jax.jit(functools.partial(self.ref.served_gap, first=self.P - 1,
                                            cfg=cfg, quant=quant))

@@ -46,7 +46,7 @@ class Driver:
         self.name = traffic["program"]
         bench = get_bench(self.name)
         prog = bench.program
-        with span("detect"):
+        with ctx.part("detect"):
             self.tpu_plan = synthesize_tpu(prog, max_delta=bench.max_delta,
                                            compiler=Compiler())
         if not self.tpu_plan.consistent:
@@ -57,12 +57,12 @@ class Driver:
         self.input_names = sorted(a for a in prog.arrays
                                   if a != prog.out.array)
         self.scalars = dict(traffic.get("scalars", {}))
-        with span("inputs"):
+        with ctx.part("inputs"):
             self.arrays = make_inputs(ctx.seed, self.input_names, self.shape)
         self.fn = jax.jit(functools.partial(
             stencil_apply, prog, scalars=self.scalars, mode=self.plan.mode,
             interpret=ctx.interpret))
-        with span("warmup"):
+        with ctx.part("warmup"):
             self.out = self.fn(self.arrays)
             self.out.block_until_ready()
         self.sweeps = 0

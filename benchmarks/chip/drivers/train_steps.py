@@ -4,19 +4,24 @@ Built as ``repro.launch.train`` builds it: ``make_train_step`` under
 ``jax.jit`` with parameters and optimizer state donated, the optimizer
 state made by the program's ``init_opt_state`` under ``jit``.  Batches
 come from the program's ``TokenPipeline`` seeded with ``--seed``, are
-placed on the device per step, and the host reads the loss after every
-step, as the launch loop does.  The weights are made from the seed by
+placed on the device per step.  The host reads each step's loss
+``ahead_steps`` steps late (the traffic's), so that that many steps
+stay queued on the device while the host waits in a read; ``finish``
+waits for every step sent.  The weights are made from the seed by
 the configuration's reference (``init_weights``) in one jitted call.
 
 Set-up drives the step through its first three steps with the window's
 own call and feed; their losses, the first step's gradient (from the
-optimizer's first moment) and the change of the parameters after the
-three are kept and compared with the reference after the window.
+optimizer's first moment, copied to the host) and the change of the
+parameters after the three are kept and compared with the reference
+after the window.
 """
 
 from __future__ import annotations
 
 import functools
+import sys
+from collections import deque
 
 import jax
 import jax.numpy as jnp
@@ -44,7 +49,7 @@ class Driver:
         self.ref = ctx.reference()
         self.key = seed_key(ctx.seed)
         model = build_model(model_config(cfg))
-        with span("weights"):
+        with ctx.part("weights"):
             weights = jax.jit(functools.partial(self.ref.init_weights, cfg=cfg))(
                 self.key)
             self.params = program_params(model, weights)
@@ -57,17 +62,37 @@ class Driver:
             vocab=cfg["vocab_size"], seq_len=tr["seq_len"],
             global_batch=tr["batch"], seed=ctx.seed))
         self.tokens_per_step = tr["batch"] * tr["seq_len"]
+        self.ahead = tr["ahead_steps"]
         self.step = 0
         self.losses = []
+        self.pending = deque()
         self.steps_in_window = 0
-        self.unit()
-        self.grad_norms = {n: v / (1 - self.opt["b1"])
-                           for n, v in leaf_norms(self.opt_state.mu).items()}
-        for _ in range(CHECKED_STEPS - 1):
+        with ctx.part("first_step"):
             self.unit()
-        self.change_norms = self._change_norms()
+        with ctx.part("first_grad"):
+            self.grad_norms = {n: v / (1 - self.opt["b1"]) for n, v in
+                               leaf_norms(self.opt_state.mu).items()}
+            self.first_grad = self._first_grad()
+        with ctx.part("checked_steps"):
+            for _ in range(CHECKED_STEPS - 1):
+                self.unit()
+        with ctx.part("change_norms"):
+            self.change_norms = self._change_norms()
+        self.finish()
         self.first_losses = list(self.losses)
         self.steps_in_window = 0
+
+    def _first_grad(self):
+        """The first step's gradient as the optimizer took it (its first
+        moment over 1 - b1), per leaf in float32 on the host's CPU
+        device."""
+        cpu = jax.devices("cpu")[0]
+        unbias = jax.jit(lambda m: m / (1 - self.opt["b1"]))
+        out = {}
+        for path, m in jax.tree_util.tree_flatten_with_path(
+                self.opt_state.mu)[0]:
+            out[leaf_name(path)] = unbias(jax.device_put(m, cpu))
+        return jax.block_until_ready(out)
 
     def _change_norms(self):
         """Per leaf, the norm of the parameters' change since the seeded
@@ -92,9 +117,17 @@ class Driver:
                      for k, v in self.pipe.batch_at(self.step).items()}
             self.params, self.opt_state, metrics = self.step_fn(
                 self.params, self.opt_state, batch)
-            self.losses.append(float(metrics["loss"]))
+            self.pending.append(metrics["loss"])
+            if len(self.pending) > self.ahead:
+                self.losses.append(float(self.pending.popleft()))
         self.step += 1
         self.steps_in_window += 1
+
+    def finish(self):
+        """Waits for every step sent, reading their losses in order."""
+        with span("train_drain"):
+            while self.pending:
+                self.losses.append(float(self.pending.popleft()))
 
     def facts(self, window_s: float):
         tr = self.ctx.traffic
@@ -119,36 +152,60 @@ class Driver:
 
     def _reference(self, **kw):
         with span("reference"):
-            return self.ref.train_steps(self.key, self.ctx.config, self.opt,
-                                        self._batches(), **kw)
+            out = self.ref.train_steps(self.key, self.ctx.config, self.opt,
+                                       self._batches(), **kw)
+        print("[reference] " + ", ".join(
+            f"{k} {v:.3f} s" for k, v in out.pop("seconds").items()),
+            file=sys.stderr)
+        return out
+
+    def _mine(self):
+        return {"losses": self.first_losses, "grad_norms": self.grad_norms,
+                "change_norms": self.change_norms}
 
     def checks(self):
-        mine = {"losses": self.first_losses, "grad_norms": self.grad_norms,
-                "change_norms": self.change_norms}
-        gaps = compare(mine, self._reference())
+        ref = self._reference(grad_against=self.first_grad)
+        self.first_grad = None
+        gaps = compare(self._mine(), ref, ref["grad_diff_norms"])
         limits = self.ctx.traffic["limits"]
-        return {k: {"value": v, "limit": limits[k]} for k, v in gaps.items()}
+        return {k: {"value": v, "limit": limits[k]} for k, v in gaps.items()
+                if k in limits}
 
     def control(self):
-        """The reference in fp8 (the control) and the reference over half
-        of each batch's tokens (a fault), each in the program's place,
-        with every side's losses."""
-        ref = self._reference()
-        fp8 = self._reference(quant="fp8")
-        half = self._reference(half_batch=True)
-        return {"control": compare(fp8, ref),
-                "half_batch": compare(half, ref),
+        """The program's numbers (as ``checks`` gives them), and the same
+        numbers of the reference in fp8 (the control) and of the
+        reference over half of each batch's tokens (a fault), each in the
+        program's place, with every side's losses."""
+        ref = self._reference(keep_grad=True)
+        against = ref.pop("first_grad")
+        diff = jax.jit(lambda a, b: jnp.linalg.norm((a - b).ravel()))
+        program = compare(self._mine(), ref, {
+            n: float(diff(a, against[n])) for n, a in self.first_grad.items()})
+        self.first_grad = None
+        fp8 = self._reference(quant="fp8", grad_against=against)
+        half = self._reference(half_batch=True, grad_against=against)
+        return {"program": program,
+                "control": compare(fp8, ref, fp8["grad_diff_norms"]),
+                "half_batch": compare(half, ref, half["grad_diff_norms"]),
                 "losses": {"program": self.first_losses,
                            "reference": ref["losses"],
                            "control": fp8["losses"],
                            "half_batch": half["losses"]}}
 
 
-def compare(mine, ref):
-    """The three numbers compared: the relative gap of the first step's
-    loss, and, by the worst leaf, the gap of the first gradient's norm and
-    of the change's norm after the checked steps (leaves whose reference
-    gradient is nought left out of the latter).
+def compare(mine, ref, grad_diff):
+    """Four readings: the relative gap of the first step's loss; by the
+    worst leaf, the gap of the first gradient's norm and of the change's
+    norm after the checked steps (leaves whose reference gradient is
+    nought left out of the latter); and by the worst leaf the norm of the
+    first gradient's difference from the reference's (``grad_diff``, per
+    leaf), each leaf against the larger of its own and the median leaf's
+    reference norm.  The last follows the gradient's direction, which the
+    gaps of norms and a mean loss do not: float8's rounding leaves those
+    about where bfloat16's does on some seeds.  A reading is compared
+    where the traffic gives it a limit; the first step's loss gap has
+    none, since neither the control nor a fault reads far enough above
+    sound runs (PERF.md, section 6).
 
     The later steps' losses are not compared: at the warm-up's learning
     rates Adam's first updates move every weight by about the learning
@@ -165,4 +222,6 @@ def compare(mine, ref):
         "grad_norm_gap": worst_leaf_gap(mine["grad_norms"], g, leaves),
         "change_norm_gap": worst_leaf_gap(mine["change_norms"],
                                           ref["change_norms"], moved),
+        "grad_diff_gap": max(grad_diff[n] / max(g[n], median, 1e-30)
+                             for n in leaves),
     }

@@ -2,8 +2,10 @@
 
 from __future__ import annotations
 
+import contextlib
 import dataclasses
-from typing import Any, Optional
+import time
+from typing import Any, Dict, Optional
 
 from .spec import Cell
 
@@ -14,6 +16,19 @@ class RunContext:
     seed: int
     devices: Any
     trace: Optional[Any] = None     # harness.trace.Trace of a traced run
+    # seconds of the driver's set-up by part, logged by run.py
+    setup_parts: Dict[str, float] = dataclasses.field(default_factory=dict)
+
+    @contextlib.contextmanager
+    def part(self, name: str):
+        """A part of set-up: a host span, and its seconds added to
+        ``setup_parts[name]``."""
+        from .trace import span
+        t0 = time.perf_counter()
+        with span(name):
+            yield
+        self.setup_parts[name] = (self.setup_parts.get(name, 0.0)
+                                  + time.perf_counter() - t0)
 
     @property
     def config(self):

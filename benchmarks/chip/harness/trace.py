@@ -11,7 +11,8 @@ span ``window``:
   predicate selects (a kernel's time, or all operations' time; a loop's
   event contains its body's, so loops are left out of sums);
 - idle gaps: the stretches of the window in which no operation ran,
-  each put down to the innermost host span that covers its middle.
+  each put down to the innermost host span that covers its middle, as
+  ``<span>/dispatch`` where one of JAX's dispatch events covers it too.
 
 Per-device numbers are averaged over the chips used.
 """
@@ -30,6 +31,8 @@ SPAN_PREFIX = "bench/"
 WINDOW = SPAN_PREFIX + "window"
 # operations that contain others on the same line (a loop and its body)
 CONTAINERS = ("%while", "%conditional", "%call")
+# host events of JAX's dispatch: a jitted call, and its launch
+DISPATCH = ("PjitFunction(", "PJRT_LoadedExecutable_Execute")
 
 Op = Tuple[str, int, int]            # (name, start_ns, end_ns)
 
@@ -54,9 +57,11 @@ def op_label(hlo: str) -> str:
 
 class Trace:
     def __init__(self, devices: Dict[str, List[Op]], spans: List[Op],
-                 kernel_names: Optional[Sequence[str]] = None):
+                 kernel_names: Optional[Sequence[str]] = None,
+                 dispatch: Iterable[Op] = ()):
         self.devices = devices
         self.spans = spans
+        self.dispatch = self._union(list(dispatch))
         self.kernel_names = tuple(kernel_names or ())
         wins = [s for s in spans if s[0] == WINDOW]
         if not wins:
@@ -71,7 +76,7 @@ class Trace:
                   kernel_names: Optional[Sequence[str]] = None) -> "Trace":
         devices, host = read_xplane(path)
         spans = [ev for ev in host if ev[0].startswith(SPAN_PREFIX)]
-        return cls(devices, spans, kernel_names)
+        return cls(devices, spans, kernel_names, dispatch_events(host))
 
     # -- helpers -----------------------------------------------------------
     def _clipped(self, ops: Iterable[Op]) -> List[Op]:
@@ -131,18 +136,23 @@ class Trace:
 
     def host_activity(self, times: Sequence[int]) -> List[str]:
         """For each of the ascending ``times``, the innermost host span
-        covering it, other than the window."""
+        covering it, other than the window, and ``/dispatch`` after it
+        where a dispatch event covers it too."""
         spans = sorted((s for s in self.spans if s[0] != WINDOW),
                        key=lambda s: s[1])
-        out, active, i = [], [], 0
+        out, active, i, j = [], [], 0, 0
         for t in times:
             while i < len(spans) and spans[i][1] <= t:
                 active.append(spans[i])
                 i += 1
             active = [s for s in active if s[2] > t]
             best = min(active, key=lambda s: s[2] - s[1], default=None)
-            out.append(best[0][len(SPAN_PREFIX):] if best
-                       else "outside any span")
+            label = best[0][len(SPAN_PREFIX):] if best else "outside any span"
+            while j < len(self.dispatch) and self.dispatch[j][1] <= t:
+                j += 1
+            if j < len(self.dispatch) and self.dispatch[j][0] <= t:
+                label += "/dispatch"
+            out.append(label)
         return out
 
     def breakdown(self, top: int = 10) -> Dict[str, list]:
@@ -164,6 +174,11 @@ class Trace:
         rank = lambda d: [[k, v] for k, v in sorted(
             d.items(), key=lambda kv: -kv[1])[:top]]
         return {"device_ops": rank(by_op), "idle_gaps": rank(by_host)}
+
+
+def dispatch_events(host: Iterable[Op]) -> List[Op]:
+    """The host events in which JAX dispatches a jitted call."""
+    return [ev for ev in host if ev[0].startswith(DISPATCH)]
 
 
 def read_xplane(path: str, device_prefix: str = DEVICE_PREFIX):

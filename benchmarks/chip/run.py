@@ -9,8 +9,10 @@ readers are found by name (``harness/spec.py``).  A run:
 1. refuses to start without a TPU, or with fewer chips than the cell asks;
 2. builds and warms up everything the cell's traffic uses (``setup_s``,
    from process start to the first timed unit);
-3. drives timed units back to back for ``--seconds`` (the window ends
-   when the last unit that the window started is done);
+3. drives timed units back to back for ``--seconds``; when the time is
+   up it starts no more, waits for all it started (a driver that keeps
+   work queued ahead waits in its ``finish``), and reads the clock: the
+   window ends when the last unit that the window started is done;
 4. reads the device's peak memory, frees the program's state, and
    compares what the window produced with the plain reference;
 5. prints the compared numbers beside their limits on standard error,
@@ -80,25 +82,51 @@ def memory_peak(devices) -> Optional[int]:
     return max(peaks) if peaks else None
 
 
+def unit_summary(unit_s: List[float]) -> str:
+    """The window's units by host time: their count, median and longest,
+    and the seconds that units over twice the median took beyond it (a
+    stall of the host or the device shows there)."""
+    ordered = sorted(unit_s)
+    median = ordered[len(ordered) // 2]
+    slow = [u for u in unit_s if u > 2 * median]
+    return (f"units {len(unit_s)}, median {median:.6f} s, longest "
+            f"{ordered[-1]:.6f} s, {len(slow)} over twice the median "
+            f"losing {sum(u - median for u in slow):.6f} s")
+
+
+def finish(drv) -> None:
+    """Waits for the work a driver keeps queued ahead, where it keeps any."""
+    if hasattr(drv, "finish"):
+        drv.finish()
+
+
 def run_cell(cell: spec_mod.Cell, seed: int, seconds: float, trace: bool,
              devices, t_start: float, log=print) -> Dict[str, Any]:
     """Set up, time, check and report one run; returns the result line."""
     from harness import trace as trace_mod
 
     ctx = RunContext(cell=cell, seed=seed, devices=devices)
+    ctx.setup_parts["start"] = time.perf_counter() - t_start
     drv = cell.driver().Driver(ctx)
     setup_s = time.perf_counter() - t_start
     log(f"[bench] {cell.name}: set-up {setup_s:.3f} s", file=sys.stderr)
+    log("[setup] " + ", ".join(f"{k} {v:.3f} s" for k, v in
+                               ctx.setup_parts.items()), file=sys.stderr)
 
     with trace_mod.capture(trace) as captured:
         drv.begin()
         with trace_mod.span("window"):
             w0 = time.perf_counter()
             t = w0
+            unit_s = []
             while t - w0 < seconds:
                 drv.unit()
+                unit_s.append(time.perf_counter() - t)
                 t = time.perf_counter()
+            finish(drv)
+            t = time.perf_counter()
     window_s = t - w0
+    log(f"[window] {unit_summary(unit_s)}", file=sys.stderr)
     peak = memory_peak(devices)
     facts = drv.facts(window_s)
 

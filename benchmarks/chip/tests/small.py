@@ -32,25 +32,10 @@ SMALL_TRAFFIC = {
 CPU_PEAKS = dict(peaks.PEAKS["TPU v5 lite"])
 
 
-def bench() -> Dict[str, Any]:
-    """``BENCHMARK.json``, with the entries of the model cells that the
-    harness's drivers are tested on added where it lacks them."""
-    b = spec.load_json(spec.CHECKOUT / "BENCHMARK.json")
-    extra = spec.load_json(os.path.join(HERE, "data", "model_cells.json"))
-    for key, entries in extra.items():
-        have = {e["name"] for e in b[key]}
-        b[key] = b[key] + [e for e in entries if e["name"] not in have]
-    return b
-
-
-def find_cell(name: str) -> spec.Cell:
-    return spec.find_cell(name, bench())
-
-
 def small_cell(name, config: Optional[Dict[str, Any]] = None,
                traffic: Optional[Dict[str, Any]] = None) -> spec.Cell:
     cell = copy.deepcopy(name if isinstance(name, spec.Cell)
-                         else find_cell(name))
+                         else spec.find_cell(name))
     cell.config.update(SMALL_CONFIG.get(cell.config_name, {}))
     cell.traffic.update(SMALL_TRAFFIC.get(cell.traffic_name, {}))
     cell.config.update(config or {})
@@ -71,4 +56,4 @@ def run_cell_small(cell: spec.Cell, seconds: float = 0.5,
 
 
 def run_small(name: str, **kw) -> Dict[str, Any]:
-    return run_cell_small(find_cell(name), **kw)
+    return run_cell_small(spec.find_cell(name), **kw)

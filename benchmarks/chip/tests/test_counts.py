@@ -4,8 +4,7 @@ import math
 
 import pytest
 
-import small
-from harness import counts
+from harness import counts, spec
 
 GB = 1e9
 
@@ -35,7 +34,7 @@ def test_stencil_points():
 
 
 def test_olmo_flops_per_token():
-    cfg = small.find_cell("olmo-1b.train").config
+    cfg = spec.find_cell("olmo-1b.train").config
     s = counts.lm_sizes(cfg)
     # 16 layers of 4*2048^2 attention + 3*2048*8192 MLP, and the tied table
     assert s["params"] == 16 * (4 * 2048 ** 2 + 3 * 2048 * 8192) + 50304 * 2048
@@ -48,7 +47,7 @@ def test_olmo_param_count_matches_the_program():
     import jax
     from harness.lm import model_config
     from repro.models import build_model, unbox
-    cfg = small.find_cell("olmo-1b.train").config
+    cfg = spec.find_cell("olmo-1b.train").config
     model = build_model(model_config(cfg))
     tree = jax.eval_shape(lambda: unbox(model.init(jax.random.PRNGKey(0))))
     n = sum(math.prod(x.shape) for x in jax.tree_util.tree_leaves(tree))
@@ -56,7 +55,7 @@ def test_olmo_param_count_matches_the_program():
 
 
 def test_decode_step_bound_by_bytes():
-    cfg = small.find_cell("olmo-1b.decode").config
+    cfg = spec.find_cell("olmo-1b.decode").config
     peaks = {"bf16_flops_s": 197e12, "hbm_bytes_s": 819e9}
     s = counts.lm_sizes(cfg)
     kv = 2 * 16 * 2000 * 16 * 16 * 128 * 2

@@ -182,4 +182,68 @@ def test_control_is_not_correct(cell, config, traffic):
     import control
     cell = small.small_cell(cell, config=config, traffic=traffic)
     got = control.readings(cell, 2**31 + 5, 0.3, jax.devices())["control"]
-    assert any(got[k] > cell.traffic["limits"][k] for k in got), got
+    limits = cell.traffic["limits"]
+    assert any(got[k] > limits[k] for k in got if k in limits), got
+
+
+def test_decode_check_spreads_its_rows_over_the_batch():
+    """The decode check draws one request from each of ``check_requests``
+    equal stretches of the batch, the same ones for the same seed."""
+    import types
+    cell = spec.find_cell("olmo-1b.decode")
+    rows_of = cell.driver().Driver._check_rows
+    n = cell.traffic["check_requests"]
+    for seed in (2**31 + 3, 2**33 + 7):
+        fake = types.SimpleNamespace(B=16, ctx=types.SimpleNamespace(
+            seed=seed, traffic={"check_requests": n}))
+        rows = rows_of(fake)
+        assert rows == rows_of(fake)
+        assert [r * n // 16 for r in rows] == list(range(n))
+
+
+def test_reference_gradient_difference_from_itself_is_nought():
+    """The train reference's ``grad_diff_norms`` against its own first
+    gradient read 0, and against another seed's do not."""
+    import jax
+    from harness.context import seed_key
+    cell = small.small_cell("olmo-1b.train")
+    ref, cfg = cell.reference(), cell.config
+    opt = cell.traffic["optimizer"]
+    rng = jax.random.PRNGKey(1)
+    toks = jax.random.randint(rng, (1, 9), 0, cfg["vocab_size"])
+    batches = [(toks[:, :-1], toks[:, 1:])]
+    one = ref.train_steps(seed_key(5), cfg, opt, batches, keep_grad=True)
+    same = ref.train_steps(seed_key(5), cfg, opt, batches,
+                           grad_against=one["first_grad"])
+    other = ref.train_steps(seed_key(6), cfg, opt, batches,
+                            grad_against=one["first_grad"])
+    assert max(same["grad_diff_norms"].values()) == 0.0
+    assert min(other["grad_diff_norms"].values()) > 0.0
+
+
+
+def test_train_losses_read_late_are_the_same_losses():
+    """The train driver keeps ``ahead_steps`` steps queued, reads their
+    losses in order, and ``finish`` drains the queue: the losses equal
+    those read after every step."""
+    import jax
+    import run
+    from harness.context import RunContext
+
+    def losses(ahead_steps):
+        cell = small.small_cell("olmo-1b.train",
+                                traffic={"ahead_steps": ahead_steps})
+        drv = cell.driver().Driver(RunContext(cell=cell, seed=2**31 + 5,
+                                              devices=jax.devices()))
+        assert not drv.pending
+        drv.begin()
+        for _ in range(5):
+            drv.unit()
+        assert len(drv.pending) == ahead_steps
+        run.finish(drv)
+        assert not drv.pending and drv.steps_in_window == 5
+        return drv.losses
+
+    late = losses(3)
+    assert len(late) == 3 + 5
+    assert late == losses(0)

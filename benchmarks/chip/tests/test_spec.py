@@ -22,6 +22,30 @@ def test_every_cell_resolves():
         assert cell.end_to_end and cell.per_layer
 
 
+CELLS = [w["name"] for w in
+         spec.load_json(CHECKOUT / "BENCHMARK.json")["workloads"]]
+
+
+@pytest.mark.parametrize("name", CELLS)
+def test_driver_reports_each_end_to_end_metric(name):
+    """Every end-to-end metric that applies to a cell, set-up aside, is
+    one that the cell's driver reports (``run.py`` refuses a run that
+    lacks one); a metric whose ``workloads`` name the wrong cell fails
+    here.  The driver runs a few units at the small sizes."""
+    import jax
+    import small
+    from harness.context import RunContext
+    cell = small.small_cell(name)
+    drv = cell.driver().Driver(RunContext(cell=cell, seed=2**31 + 13,
+                                          devices=jax.devices()))
+    drv.begin()
+    for _ in range(3):
+        drv.unit()
+    reported = set(drv.facts(1.0)["end_to_end"])
+    wanted = {m["name"] for m in cell.end_to_end} - {"setup_s"}
+    assert wanted and wanted <= reported, (wanted, reported)
+
+
 def test_unknown_cell():
     with pytest.raises(KeyError):
         spec.find_cell("no-such.cell")

@@ -5,28 +5,32 @@ import os
 
 import pytest
 
-from harness.trace import SPAN_PREFIX, WINDOW, Trace, op_label, read_xplane
+from harness import spec
+from harness.trace import (SPAN_PREFIX, WINDOW, Trace, dispatch_events,
+                           op_label, read_xplane)
 
 DATA = os.path.join(os.path.dirname(os.path.abspath(__file__)), "data")
 RECORDED = os.path.join(DATA, "jacobi_1024.xplane.pb")
 KERNEL = 'custom_call_target="tpu_custom_call"'
 
 
-def recorded() -> Trace:
+def recorded(dispatch: bool = True) -> Trace:
     """Three sweeps of the jitted jacobi ``stencil_apply`` (plan
     ``paper``) at 1024x1024 on one TPU v5e chip, each under a host span
     ``sweep``; the window runs from the first sweep's start to the last
-    one's end."""
+    one's end.  JAX's dispatch events are kept where ``dispatch``."""
     devices, host = read_xplane(RECORDED)
     sweeps = [(SPAN_PREFIX + n, s, e) for n, s, e in host if n == "sweep"]
     window = (WINDOW, min(s for _, s, _ in sweeps), max(e for _, _, e in sweeps))
-    return Trace(devices, [window] + sweeps, kernel_names=[KERNEL])
+    return Trace(devices, [window] + sweeps, kernel_names=[KERNEL],
+                 dispatch=dispatch_events(host) if dispatch else ())
 
 
-def made_up(ops, spans=(), window=(0, 100)):
+def made_up(ops, spans=(), window=(0, 100), dispatch=()):
     spans = [(WINDOW,) + tuple(window)] + [
         (SPAN_PREFIX + n, s, e) for n, s, e in spans]
-    return Trace({"/device:TPU:0": list(ops)}, spans, kernel_names=["kern"])
+    return Trace({"/device:TPU:0": list(ops)}, spans, kernel_names=["kern"],
+                 dispatch=dispatch)
 
 
 def test_busy_is_the_union_of_operations():
@@ -50,13 +54,18 @@ def test_loops_count_once():
     assert t.kernel_s() == pytest.approx(25e-9)
 
 
-def test_idle_gaps_go_to_the_host_span_covering_them():
+@pytest.mark.parametrize("dispatch,label", [
+    ((), "decode_step"),
+    ([("PjitFunction(decode)", 70, 80),
+      ("PJRT_LoadedExecutable_Execute", 72, 78)], "decode_step/dispatch"),
+])
+def test_idle_gaps_go_to_the_host_span_covering_them(dispatch, label):
     t = made_up([("a", 0, 10), ("b", 30, 60), ("c", 90, 100)],
                 spans=[("prefill", 5, 35), ("decode_step", 55, 95),
-                       ("sample", 70, 75)])
+                       ("sample", 70, 75)], dispatch=dispatch)
     gaps = dict(t.breakdown()["idle_gaps"])
     assert gaps == {"prefill": pytest.approx(20e-9),
-                    "decode_step": pytest.approx(30e-9)}
+                    label: pytest.approx(30e-9)}
 
 
 def test_op_label_keeps_name_type_and_target():
@@ -78,4 +87,27 @@ def test_recorded_trace():
     b = t.breakdown()
     assert b["device_ops"][0][0].endswith("tpu_custom_call")
     assert sum(s for _, s in b["idle_gaps"]) == pytest.approx(window - busy)
-    assert {n for n, _ in b["idle_gaps"]} <= {"sweep", "outside any span"}
+    assert {n for n, _ in b["idle_gaps"]} <= {
+        "sweep", "sweep/dispatch", "outside any span"}
+
+
+def test_dispatch_labels_change_only_the_labels():
+    """On the recorded trace, gaps under JAX's dispatch events take the
+    label ``sweep/dispatch``; busy and window seconds, every device idle
+    share and the idle seconds under each span are as without them."""
+    plain, labelled = recorded(dispatch=False), recorded()
+    assert labelled.busy_s() == plain.busy_s()
+    assert labelled.window_s() == plain.window_s()
+    for name in ("device_idle.stencil", "device_idle.train",
+                 "device_idle.decode"):
+        read = spec.load_module(spec.HERE / "metrics" / f"{name}.py").read
+        assert read(None, {}, labelled) == read(None, {}, plain)
+    before = dict(plain.breakdown()["idle_gaps"])
+    after = dict(labelled.breakdown()["idle_gaps"])
+    assert "sweep/dispatch" in after and "sweep/dispatch" not in before
+    by_span = {}
+    for label, s in after.items():
+        span = label.removesuffix("/dispatch")
+        by_span[span] = by_span.get(span, 0.0) + s
+    assert by_span == pytest.approx(before)
+    assert labelled.breakdown()["device_ops"] == plain.breakdown()["device_ops"]
