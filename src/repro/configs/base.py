@@ -35,6 +35,7 @@ class ModelConfig:
     ssm_expand: int = 2
     conv_width: int = 4
     ssm_chunk: int = 256
+    ssm_norm_eps: float = 1e-6     # eps of the Mamba blocks' and final RMSNorms
     attn_every: int = 6            # hybrid: shared attn block per N ssm blocks
     # --- VLM ---
     cross_every: int = 0           # a cross-attn layer every N layers

@@ -164,13 +164,15 @@ def init_norm(key, d: int, kind: str, dtype=jnp.float32) -> Params:
 
 
 def apply_norm(params: Params, x: jnp.ndarray, kind: str,
-               impl: str = "lean") -> jnp.ndarray:
+               impl: str = "lean", eps: Optional[float] = None) -> jnp.ndarray:
+    """``eps`` None: the norm's own default."""
+    kw = {"impl": impl} if eps is None else {"impl": impl, "eps": eps}
     if kind == "rmsnorm":
-        return rmsnorm(x, params["scale"], impl=impl)
+        return rmsnorm(x, params["scale"], **kw)
     if kind == "layernorm":
-        return layernorm(x, params["scale"], params["bias"], impl=impl)
+        return layernorm(x, params["scale"], params["bias"], **kw)
     if kind == "nonparametric":
-        return layernorm(x, None, None, impl=impl)
+        return layernorm(x, None, None, **kw)
     raise ValueError(kind)
 
 

@@ -424,12 +424,25 @@ class VLMModel(Model):
 # ---------------------------------------------------------------------------
 
 class SSMModel(Model):
+    """Pre-norm Mamba-2 blocks over a float32 residual stream (the
+    published ``residual_in_fp32``): each norm reads the float32 residual
+    and hands the mixer and the loss the compute dtype."""
+
     def _ssm_cfg(self) -> m2.SSMConfig:
         cfg = self.cfg
         return m2.SSMConfig(d_model=cfg.d_model, d_state=cfg.ssm_state,
                             head_dim=cfg.ssm_head_dim, expand=cfg.ssm_expand,
                             conv_width=cfg.conv_width, chunk=cfg.ssm_chunk,
+                            norm_eps=cfg.ssm_norm_eps,
                             mm_dtype=cfg.ssm_mm_dtype)
+
+    def _norm(self, p, x):
+        cfg = self.cfg
+        return apply_norm(p, x, cfg.norm, impl=cfg.norm_impl,
+                          eps=cfg.ssm_norm_eps).astype(self.dtype)
+
+    def _embed(self, params, tokens):
+        return params["embed"]["table"][tokens].astype(jnp.float32)
 
     def init(self, key) -> Params:
         cfg = self.cfg
@@ -456,14 +469,19 @@ class SSMModel(Model):
 
         def block(x, bp):
             x = constrain_batch(x, self.mesh)
-            h = apply_norm(bp["ln"], x, cfg.norm, impl=cfg.norm_impl)
-            y = x + m2.apply_mamba2(bp["mamba"], h, scfg)
-            return constrain_batch(y, self.mesh), jnp.float32(0)
+            y = m2.apply_mamba2(bp["mamba"], self._norm(bp["ln"], x), scfg)
+            return constrain_batch(x + y.astype(x.dtype), self.mesh), \
+                jnp.float32(0)
 
         if cfg.remat == "block":
             block = jax.checkpoint(block)
         x, _ = jax.lax.scan(block, x, params["blocks"])
         return x, jnp.float32(0)
+
+    def hidden(self, params, batch):
+        x, aux = self._backbone(params, self._embed(params, batch["tokens"]),
+                                batch)
+        return self._norm(params["ln_f"], x), aux
 
     def init_cache(self, batch_size: int, seq_len: int):
         scfg = self._ssm_cfg()
@@ -478,19 +496,18 @@ class SSMModel(Model):
     def prefill(self, params, batch, max_len: Optional[int] = None):
         cfg = self.cfg
         scfg = self._ssm_cfg()
-        x = params["embed"]["table"][batch["tokens"]]
+        x = self._embed(params, batch["tokens"])
 
         from repro.sharding.rules import constrain_batch
 
         def block(x, bp):
             x = constrain_batch(x, self.mesh)
-            h = apply_norm(bp["ln"], x, cfg.norm, impl=cfg.norm_impl)
-            y, (cs, ss) = m2.apply_mamba2(bp["mamba"], h, scfg,
-                                          return_state=True)
-            return constrain_batch(x + y, self.mesh), (cs, ss)
+            y, (cs, ss) = m2.apply_mamba2(bp["mamba"], self._norm(bp["ln"], x),
+                                          scfg, return_state=True)
+            return constrain_batch(x + y.astype(x.dtype), self.mesh), (cs, ss)
 
         x, (convs, ssms) = jax.lax.scan(block, x, params["blocks"])
-        h = apply_norm(params["ln_f"], x, cfg.norm, impl=cfg.norm_impl)
+        h = self._norm(params["ln_f"], x)
         logits = jnp.einsum("bd,vd->bv", h[:, -1].astype(jnp.float32),
                             params["embed"]["table"].astype(jnp.float32))
         logits = logits[:, :cfg.vocab]
@@ -501,17 +518,17 @@ class SSMModel(Model):
     def decode_step(self, params, tokens, cache):
         cfg = self.cfg
         scfg = self._ssm_cfg()
-        x = params["embed"]["table"][tokens]            # (B, D)
+        x = self._embed(params, tokens)                  # (B, D)
 
         def block(x, inp):
             bp, cs, ss = inp
-            h = apply_norm(bp["ln"], x, cfg.norm, impl=cfg.norm_impl)
-            y, (cs, ss) = m2.decode_step(bp["mamba"], h, (cs, ss), scfg)
-            return x + y, (cs, ss)
+            y, (cs, ss) = m2.decode_step(bp["mamba"], self._norm(bp["ln"], x),
+                                         (cs, ss), scfg)
+            return x + y.astype(x.dtype), (cs, ss)
 
         x, (convs, ssms) = jax.lax.scan(
             block, x, (params["blocks"], cache["conv"], cache["ssm"]))
-        h = apply_norm(params["ln_f"], x, cfg.norm, impl=cfg.norm_impl)
+        h = self._norm(params["ln_f"], x)
         logits = jnp.einsum("bd,vd->bv", h.astype(jnp.float32),
                             params["embed"]["table"].astype(jnp.float32))
         logits = logits[:, :cfg.vocab]
@@ -531,6 +548,10 @@ class HybridModel(SSMModel):
         super().__init__(cfg, mesh)
         self.n_super = cfg.n_layers // cfg.attn_every
         self.n_trail = cfg.n_layers - self.n_super * cfg.attn_every
+
+    def _embed(self, params, tokens):
+        # the hybrid's residual stays in the compute dtype
+        return params["embed"]["table"][tokens]
 
     def init(self, key) -> Params:
         cfg = self.cfg
@@ -562,7 +583,7 @@ class HybridModel(SSMModel):
         scfg = self._ssm_cfg()
 
         def mblock(x, bp):
-            h = apply_norm(bp["ln"], x, cfg.norm, impl=cfg.norm_impl)
+            h = self._norm(bp["ln"], x)
             return x + m2.apply_mamba2(bp["mamba"], h, scfg), None
 
         from repro.sharding.rules import constrain_batch
@@ -605,13 +626,13 @@ class HybridModel(SSMModel):
     def prefill(self, params, batch, max_len: Optional[int] = None):
         cfg, mesh = self.cfg, self.mesh
         scfg = self._ssm_cfg()
-        x = params["embed"]["table"][batch["tokens"]]
+        x = self._embed(params, batch["tokens"])
 
         from repro.sharding.rules import constrain_batch
 
         def mblock(x, bp):
             x = constrain_batch(x, mesh)
-            h = apply_norm(bp["ln"], x, cfg.norm, impl=cfg.norm_impl)
+            h = self._norm(bp["ln"], x)
             y, (cs, ss) = m2.apply_mamba2(bp["mamba"], h, scfg,
                                           return_state=True)
             return constrain_batch(x + y, mesh), (cs, ss)
@@ -629,7 +650,7 @@ class HybridModel(SSMModel):
             x, (ct, st) = jax.lax.scan(mblock, x, params["trail"])
             conv_all = jnp.concatenate([conv_all, ct], 0)
             ssm_all = jnp.concatenate([ssm_all, st], 0)
-        h = apply_norm(params["ln_f"], x, cfg.norm, impl=cfg.norm_impl)
+        h = self._norm(params["ln_f"], x)
         logits = jnp.einsum("bd,vd->bv", h[:, -1].astype(jnp.float32),
                             params["embed"]["table"].astype(jnp.float32))
         logits = logits[:, :cfg.vocab]
@@ -642,13 +663,13 @@ class HybridModel(SSMModel):
     def decode_step(self, params, tokens, cache):
         cfg, mesh = self.cfg, self.mesh
         scfg = self._ssm_cfg()
-        x = params["embed"]["table"][tokens]
+        x = self._embed(params, tokens)
         pos = cache["pos"]
         ne = cfg.attn_every
 
         def mblock(x, inp):
             bp, cs, ss = inp
-            h = apply_norm(bp["ln"], x, cfg.norm, impl=cfg.norm_impl)
+            h = self._norm(bp["ln"], x)
             y, (cs, ss) = m2.decode_step(bp["mamba"], h, (cs, ss), scfg)
             return x + y, (cs, ss)
 
@@ -678,7 +699,7 @@ class HybridModel(SSMModel):
                             cache["ssm"][n_in_super:]))
             conv_all = jnp.concatenate([conv_all, ct], 0)
             ssm_all = jnp.concatenate([ssm_all, st], 0)
-        h = apply_norm(params["ln_f"], x, cfg.norm, impl=cfg.norm_impl)
+        h = self._norm(params["ln_f"], x)
         logits = jnp.einsum("bd,vd->bv", h.astype(jnp.float32),
                             params["embed"]["table"].astype(jnp.float32))
         logits = logits[:, :cfg.vocab]

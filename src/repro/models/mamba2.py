@@ -44,6 +44,7 @@ class SSMConfig(NamedTuple):
     chunk: int = 256
     dt_min: float = 1e-3
     dt_max: float = 1e-1
+    norm_eps: float = 1e-6      # the gated RMSNorm's
     mm_dtype: str = "float32"   # float32 | compute: dtype of the SSD
                                 # intra-chunk matmul operands (cum/decay
                                 # math stays fp32) — §Perf hillclimb
@@ -164,9 +165,11 @@ def ssd_chunked(xh: jnp.ndarray, dt: jnp.ndarray, A: jnp.ndarray,
         dA = dtc * A[None, None, :]                # (B,Q,H) negative, fp32
         cum = jnp.cumsum(dA, axis=1)
         total = cum[:, -1]                         # (B,H)
-        # intra-chunk: M[i,j] = exp(cum_i - cum_j), i >= j
+        # intra-chunk: M[i,j] = exp(cum_i - cum_j), i >= j; masked before
+        # the exp, whose overflow above the diagonal would make the
+        # gradient 0 * inf
         diff = cum[:, :, None, :] - cum[:, None, :, :]       # (B,Qi,Qj,H)
-        decay = jnp.where(mask[None, :, :, None], jnp.exp(diff), 0.0)
+        decay = jnp.exp(jnp.where(mask[None, :, :, None], diff, -jnp.inf))
         cb = jnp.einsum("bign,bjgn->bijg", Cc, Bc, **acc32)  # (B,Q,Q,G)
         cb = jnp.repeat(cb, rep, axis=3)                     # (B,Q,Q,H)
         xdt = xc * dtc[..., None].astype(mm)                 # (B,Q,H,P)
@@ -199,26 +202,36 @@ def apply_mamba2(params: Params, x: jnp.ndarray, cfg: SSMConfig,
                  conv_state: Optional[jnp.ndarray] = None,
                  ssm_state: Optional[jnp.ndarray] = None,
                  return_state: bool = False):
-    """Full-sequence forward.  x: (B, L, D)."""
+    """Full-sequence forward.  x: (B, L, D).
+
+    Each part runs under a ``jax.named_scope`` (``mamba2_in_proj``,
+    ``mamba2_conv1d``, ``mamba2_ssd``, ``mamba2_gate_norm``,
+    ``mamba2_out_proj``), which the compiled program keeps in its
+    operations' ``op_name``, forward, recomputed and transposed."""
     Bsz, L, _ = x.shape
     H, P, ng, ns = cfg.n_heads, cfg.head_dim, cfg.n_groups, cfg.d_state
-    z, xin, Bc, Cc, dt = _split_proj(params, x, cfg)
-    conv_in = jnp.concatenate([xin, Bc, Cc], axis=-1)
-    conv_out = causal_conv1d_ref(conv_in, params["conv_w"], params["conv_b"],
-                                 conv_state)
-    xin, Bc, Cc = jnp.split(conv_out, [cfg.d_inner, cfg.d_inner + ng * ns],
-                            axis=-1)
-    A = -jnp.exp(params["a_log"])                           # (H,) negative
-    dt = jax.nn.softplus(dt.astype(jnp.float32) + params["dt_bias"])
-    xh = xin.reshape(Bsz, L, H, P)
-    Bm = Bc.reshape(Bsz, L, ng, ns)
-    Cm = Cc.reshape(Bsz, L, ng, ns)
-    y, s_final = ssd_chunked(xh, dt, A, Bm, Cm, min(cfg.chunk, L),
-                             init_state=ssm_state, mm_dtype=cfg.mm_dtype)
-    y = y + params["d_skip"][None, None, :, None] * xh
-    y = y.reshape(Bsz, L, cfg.d_inner).astype(x.dtype)
-    y = rmsnorm(y * jax.nn.silu(z), params["norm_scale"])
-    out = jnp.einsum("bld,dk->blk", y, params["w_out"]).astype(x.dtype)
+    with jax.named_scope("mamba2_in_proj"):
+        z, xin, Bc, Cc, dt = _split_proj(params, x, cfg)
+    with jax.named_scope("mamba2_conv1d"):
+        conv_in = jnp.concatenate([xin, Bc, Cc], axis=-1)
+        conv_out = causal_conv1d_ref(conv_in, params["conv_w"],
+                                     params["conv_b"], conv_state)
+        xin, Bc, Cc = jnp.split(conv_out, [cfg.d_inner, cfg.d_inner + ng * ns],
+                                axis=-1)
+    with jax.named_scope("mamba2_ssd"):
+        A = -jnp.exp(params["a_log"])                       # (H,) negative
+        dt = jax.nn.softplus(dt.astype(jnp.float32) + params["dt_bias"])
+        xh = xin.reshape(Bsz, L, H, P)
+        Bm = Bc.reshape(Bsz, L, ng, ns)
+        Cm = Cc.reshape(Bsz, L, ng, ns)
+        y, s_final = ssd_chunked(xh, dt, A, Bm, Cm, min(cfg.chunk, L),
+                                 init_state=ssm_state, mm_dtype=cfg.mm_dtype)
+        y = y + params["d_skip"][None, None, :, None] * xh
+        y = y.reshape(Bsz, L, cfg.d_inner).astype(x.dtype)
+    with jax.named_scope("mamba2_gate_norm"):
+        y = rmsnorm(y * jax.nn.silu(z), params["norm_scale"], cfg.norm_eps)
+    with jax.named_scope("mamba2_out_proj"):
+        out = jnp.einsum("bld,dk->blk", y, params["w_out"]).astype(x.dtype)
     if return_state:
         new_conv_state = jnp.concatenate(
             [jnp.zeros((Bsz, cfg.conv_width - 1, cfg.conv_dim), x.dtype),
@@ -228,27 +241,33 @@ def apply_mamba2(params: Params, x: jnp.ndarray, cfg: SSMConfig,
 
 
 def decode_step(params: Params, x_t: jnp.ndarray, state, cfg: SSMConfig):
-    """O(1) recurrent step.  x_t: (B, D); state = (conv_state, ssm_state)."""
+    """O(1) recurrent step.  x_t: (B, D); state = (conv_state, ssm_state).
+    Its parts run under ``apply_mamba2``'s scopes."""
     conv_state, ssm_state = state
     Bsz = x_t.shape[0]
     H, P, ng, ns = cfg.n_heads, cfg.head_dim, cfg.n_groups, cfg.d_state
-    z, xin, Bc, Cc, dt = _split_proj(params, x_t, cfg)
-    conv_in = jnp.concatenate([xin, Bc, Cc], axis=-1)       # (B, conv_dim)
-    conv_out, conv_state = conv1d_step(conv_in, conv_state,
-                                       params["conv_w"], params["conv_b"])
-    xin, Bc, Cc = jnp.split(conv_out, [cfg.d_inner, cfg.d_inner + ng * ns],
-                            axis=-1)
-    A = -jnp.exp(params["a_log"])
-    dt = jax.nn.softplus(dt.astype(jnp.float32) + params["dt_bias"])  # (B,H)
-    xh = xin.reshape(Bsz, H, P).astype(jnp.float32)
-    Bm = jnp.repeat(Bc.reshape(Bsz, ng, ns), H // ng, axis=1)  # (B,H,N)
-    Cm = jnp.repeat(Cc.reshape(Bsz, ng, ns), H // ng, axis=1)
-    da = jnp.exp(dt * A[None, :])                           # (B,H)
-    ssm_state = (ssm_state * da[:, :, None, None]
-                 + jnp.einsum("bhn,bhp->bhnp", Bm, xh * dt[..., None]))
-    y = jnp.einsum("bhn,bhnp->bhp", Cm, ssm_state)
-    y = y + params["d_skip"][None, :, None] * xh
-    y = y.reshape(Bsz, cfg.d_inner).astype(x_t.dtype)
-    y = rmsnorm(y * jax.nn.silu(z), params["norm_scale"])
-    out = jnp.einsum("bd,dk->bk", y, params["w_out"]).astype(x_t.dtype)
+    with jax.named_scope("mamba2_in_proj"):
+        z, xin, Bc, Cc, dt = _split_proj(params, x_t, cfg)
+    with jax.named_scope("mamba2_conv1d"):
+        conv_in = jnp.concatenate([xin, Bc, Cc], axis=-1)   # (B, conv_dim)
+        conv_out, conv_state = conv1d_step(conv_in, conv_state,
+                                           params["conv_w"], params["conv_b"])
+        xin, Bc, Cc = jnp.split(conv_out, [cfg.d_inner, cfg.d_inner + ng * ns],
+                                axis=-1)
+    with jax.named_scope("mamba2_ssd"):
+        A = -jnp.exp(params["a_log"])
+        dt = jax.nn.softplus(dt.astype(jnp.float32) + params["dt_bias"])  # (B,H)
+        xh = xin.reshape(Bsz, H, P).astype(jnp.float32)
+        Bm = jnp.repeat(Bc.reshape(Bsz, ng, ns), H // ng, axis=1)  # (B,H,N)
+        Cm = jnp.repeat(Cc.reshape(Bsz, ng, ns), H // ng, axis=1)
+        da = jnp.exp(dt * A[None, :])                       # (B,H)
+        ssm_state = (ssm_state * da[:, :, None, None]
+                     + jnp.einsum("bhn,bhp->bhnp", Bm, xh * dt[..., None]))
+        y = jnp.einsum("bhn,bhnp->bhp", Cm, ssm_state)
+        y = y + params["d_skip"][None, :, None] * xh
+        y = y.reshape(Bsz, cfg.d_inner).astype(x_t.dtype)
+    with jax.named_scope("mamba2_gate_norm"):
+        y = rmsnorm(y * jax.nn.silu(z), params["norm_scale"], cfg.norm_eps)
+    with jax.named_scope("mamba2_out_proj"):
+        out = jnp.einsum("bd,dk->bk", y, params["w_out"]).astype(x_t.dtype)
     return out, (conv_state, ssm_state)

@@ -1,2 +1,3 @@
-from .optim import OptConfig, OptState, adamw_update, init_opt_state  # noqa: F401
+from .optim import (OptConfig, OptState, adamw_update, decay_mask,  # noqa: F401
+                    init_opt_state)
 from .step import make_train_step  # noqa: F401

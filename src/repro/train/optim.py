@@ -59,9 +59,30 @@ def global_norm(tree: Any) -> jnp.ndarray:
                         for l in leaves))
 
 
+def decay_mask(boxed_params: Any) -> Any:
+    """Per leaf of the (boxed) parameters, whether weight decay applies:
+    to matrix-like leaves only, each layer's own axes counted and not the
+    ``layers`` axis a stack adds, so stacked norm scales, biases and
+    per-head vectors (Mamba-2's ``A_log``, ``D``, ``dt_bias``) are not
+    decayed.  A leaf with no logical axes counts every axis."""
+    from repro.models.common import LAYERS, LogicalArray
+
+    def matrix_like(x):
+        if isinstance(x, LogicalArray):
+            return sum(a != LAYERS for a in x.axes) >= 2
+        return x.ndim >= 2
+
+    return jax.tree_util.tree_map(
+        matrix_like, boxed_params,
+        is_leaf=lambda x: isinstance(x, LogicalArray))
+
+
 def adamw_update(cfg: OptConfig, grads: Any, state: OptState,
-                 params: Any) -> Tuple[Any, OptState, Dict[str, jnp.ndarray]]:
-    """Returns (new_params, new_state, metrics)."""
+                 params: Any, decay: Any
+                 ) -> Tuple[Any, OptState, Dict[str, jnp.ndarray]]:
+    """Returns (new_params, new_state, metrics).  ``decay``: a tree like
+    ``params`` of bools (``decay_mask``) saying which leaves weight decay
+    applies to."""
     gnorm = global_norm(grads)
     scale = jnp.minimum(1.0, cfg.clip_norm / jnp.maximum(gnorm, 1e-12))
     count = state.count + 1
@@ -69,7 +90,7 @@ def adamw_update(cfg: OptConfig, grads: Any, state: OptState,
     b1c = 1 - cfg.b1 ** count.astype(jnp.float32)
     b2c = 1 - cfg.b2 ** count.astype(jnp.float32)
 
-    def upd(p, g, m, v):
+    def upd(p, g, m, v, d):
         g = g.astype(jnp.float32) * scale
         m = cfg.b1 * m + (1 - cfg.b1) * g
         v = cfg.b2 * v + (1 - cfg.b2) * jnp.square(g)
@@ -77,7 +98,7 @@ def adamw_update(cfg: OptConfig, grads: Any, state: OptState,
         vhat = v / b2c
         step = mhat / (jnp.sqrt(vhat) + cfg.eps)
         # decoupled weight decay on matrix-like params only
-        if p.ndim >= 2:
+        if d:
             step = step + cfg.weight_decay * p.astype(jnp.float32)
         new_p = p.astype(jnp.float32) - lr * step
         return new_p.astype(p.dtype), m, v
@@ -86,7 +107,8 @@ def adamw_update(cfg: OptConfig, grads: Any, state: OptState,
     flat_g = jax.tree_util.tree_leaves(grads)
     flat_m = jax.tree_util.tree_leaves(state.mu)
     flat_v = jax.tree_util.tree_leaves(state.nu)
-    out = [upd(p, g, m, v) for p, g, m, v in zip(flat_p, flat_g, flat_m, flat_v)]
+    flat_d = jax.tree_util.tree_leaves(decay)
+    out = [upd(*a) for a in zip(flat_p, flat_g, flat_m, flat_v, flat_d)]
     new_params = jax.tree_util.tree_unflatten(tdef, [o[0] for o in out])
     new_mu = jax.tree_util.tree_unflatten(tdef, [o[1] for o in out])
     new_nu = jax.tree_util.tree_unflatten(tdef, [o[2] for o in out])

@@ -21,7 +21,7 @@ from typing import Any, Callable, Dict, Optional, Tuple
 import jax
 import jax.numpy as jnp
 
-from .optim import OptConfig, OptState, adamw_update
+from .optim import OptConfig, OptState, adamw_update, decay_mask
 
 
 def make_train_step(model, opt_cfg: OptConfig,
@@ -29,6 +29,7 @@ def make_train_step(model, opt_cfg: OptConfig,
                     compress_pod_grads: bool = False,
                     mesh=None) -> Callable:
     loss_fn = lambda p, b: model.loss(p, b)
+    decay = decay_mask(jax.eval_shape(model.init, jax.random.PRNGKey(0)))
 
     def compute_grads(params, batch):
         if accum_steps == 1:
@@ -67,7 +68,7 @@ def make_train_step(model, opt_cfg: OptConfig,
             from repro.distributed.compression import pod_compressed_mean
             grads = pod_compressed_mean(grads, mesh)
         params, opt_state, opt_metrics = adamw_update(
-            opt_cfg, grads, opt_state, params)
+            opt_cfg, grads, opt_state, params, decay)
         metrics = dict(metrics)
         metrics.update(opt_metrics)
         metrics["loss"] = loss

@@ -19,7 +19,7 @@ from repro.launch.hlo_analysis import analyze
 from repro.launch.mesh import make_host_mesh
 from repro.runtime import Heartbeat, StragglerDetector, plan_elastic
 from repro.sharding.rules import resolve_spec
-from repro.train import OptConfig, adamw_update, init_opt_state
+from repro.train import OptConfig, adamw_update, decay_mask, init_opt_state
 from repro.train.optim import global_norm, lr_schedule
 
 
@@ -34,7 +34,8 @@ def test_adamw_decreases_quadratic():
     state = init_opt_state(params)
     for _ in range(60):
         grads = {"w": 2 * params["w"]}       # d/dw ||w||^2
-        params, state, _ = adamw_update(cfg, grads, state, params)
+        params, state, _ = adamw_update(cfg, grads, state, params,
+                                        decay_mask(params))
     assert float(jnp.sum(jnp.abs(params["w"]))) < 0.5
 
 
@@ -43,7 +44,7 @@ def test_grad_clipping():
     params = {"w": jnp.zeros(4)}
     state = init_opt_state(params)
     big = {"w": jnp.full(4, 1e6)}
-    _, _, metrics = adamw_update(cfg, big, state, params)
+    _, _, metrics = adamw_update(cfg, big, state, params, decay_mask(params))
     assert float(metrics["grad_norm"]) > 1e5   # reported pre-clip
 
 
