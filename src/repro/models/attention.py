@@ -238,24 +238,34 @@ def decode_attention(params: Params, x: jnp.ndarray,
                      cache: Tuple[jnp.ndarray, jnp.ndarray],
                      pos: jnp.ndarray, cfg: AttnConfig):
     """Single-token decode: x (B, 1, D); cache k/v (B, S, KVH, Dh);
-    pos (B,) current absolute position.  Returns (out, new_cache)."""
-    ck, cv = cache
-    B, S, KV, Dh = ck.shape
-    q, k_new, v_new = qkv(params, x, pos[:, None], cfg)
-    # write the new k/v at position pos (per batch row)
-    onehot = jax.nn.one_hot(pos, S, dtype=ck.dtype)          # (B, S)
-    ck = ck * (1 - onehot[..., None, None]) + onehot[..., None, None] * k_new
-    cv = cv * (1 - onehot[..., None, None]) + onehot[..., None, None] * v_new
-    G = q.shape[2] // KV
-    qr = q.reshape(B, 1, KV, G, Dh).astype(jnp.float32)
-    s = jnp.einsum("bqhgd,bkhd->bhgqk", qr, ck.astype(jnp.float32))
-    s = s / math.sqrt(Dh)
-    valid = jnp.arange(S)[None] <= pos[:, None]              # (B, S)
-    s = jnp.where(valid[:, None, None, None], s, _NEG_INF)
-    p = jax.nn.softmax(s, axis=-1)
-    out = jnp.einsum("bhgqk,bkhd->bqhgd", p, cv.astype(jnp.float32))
-    out = out.reshape(B, 1, q.shape[2], Dh).astype(x.dtype)
-    return jnp.einsum("bshk,hkd->bsd", out, params["wo"]), (ck, cv)
+    pos (B,) current absolute position.  Returns (out, new_cache).
+
+    Both products read the cache in its own dtype and accumulate in
+    float32; the softmax is float32 and its p is cast to the cache dtype
+    for PV, as OLMo's bf16 path does.  With one query row per head XLA
+    lowers each product to a multiply and reduce that streams the cache
+    once, with no float32 copy of it.  The new k/v is written at each
+    row's own position by a select, which fuses into the copy of the
+    cache that the step returns; a scatter there compiles to two more
+    passes over the cache."""
+    with jax.named_scope("decode_attention"):
+        ck, cv = cache
+        B, S, KV, Dh = ck.shape
+        q, k_new, v_new = qkv(params, x, pos[:, None], cfg)
+        at = (jnp.arange(S)[None] == pos[:, None])[..., None, None]
+        ck = jnp.where(at, k_new.astype(ck.dtype), ck)
+        cv = jnp.where(at, v_new.astype(cv.dtype), cv)
+        G = q.shape[2] // KV
+        qr = q.reshape(B, KV, G, Dh).astype(ck.dtype)
+        s = jnp.einsum("bhgd,bkhd->bhgk", qr, ck,
+                       preferred_element_type=jnp.float32) / math.sqrt(Dh)
+        valid = jnp.arange(S)[None] <= pos[:, None]          # (B, S)
+        s = jnp.where(valid[:, None, None], s, _NEG_INF)
+        p = jax.nn.softmax(s, axis=-1).astype(cv.dtype)
+        out = jnp.einsum("bhgk,bkhd->bhgd", p, cv,
+                         preferred_element_type=jnp.float32)
+        out = out.reshape(B, 1, q.shape[2], Dh).astype(x.dtype)
+        return jnp.einsum("bshk,hkd->bsd", out, params["wo"]), (ck, cv)
 
 
 # ---------------------------------------------------------------------------
